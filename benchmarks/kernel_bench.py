@@ -410,11 +410,11 @@ def run_mesh(mesh_shape, workloads=("BERT-L1", "GPT-L1")) -> List[dict]:
     XLA_FLAGS).  On CPU the kernel path is interpret-mode emulation —
     the sweep validates dispatch + collectives, not wall-clock.
     """
-    from repro.launch.mesh import make_axis_env
+    from repro.launch.mesh import make_axis_env, make_mesh
     from repro.models.pjit_utils import use_axis_env
 
     d_, m_ = mesh_shape
-    mesh = jax.make_mesh((d_, m_), ("data", "model"))
+    mesh = make_mesh((d_, m_), ("data", "model"))
     env = make_axis_env(mesh)
     backend = detect_backend()
     kb = backend if backend == "tpu" else "interpret"
@@ -468,11 +468,11 @@ def run_mesh_quantized(mesh_shape, shape=(128, 512, 256),
     quantized problem to the reference — the smoke row IS the acceptance
     check that the quantized class stays on kernels under the mesh.
     """
-    from repro.launch.mesh import make_axis_env
+    from repro.launch.mesh import make_axis_env, make_mesh
     from repro.models.pjit_utils import use_axis_env
 
     d_, m_ = mesh_shape
-    mesh = jax.make_mesh((d_, m_), ("data", "model"))
+    mesh = make_mesh((d_, m_), ("data", "model"))
     env = make_axis_env(mesh)
     kb = _kernel_backend()
     b, k, o = shape

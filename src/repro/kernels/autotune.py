@@ -12,17 +12,22 @@ Keys are deterministic strings (shape/sparsity/dtype), so a tuned entry on
 one host applies to any run of the same problem on the same backend.
 
 Stores are additionally keyed by the **device kind** actually executing
-(``cpu-interpret.json`` vs ``tpu-interpret.json`` vs ``tpu.json``): block
-sizes timed under CPU interpret-mode emulation say nothing about Mosaic
-behavior, so an interpret-tuned entry must never be served to a TPU run.
+(``jax.devices()[0].device_kind``: ``cpu-interpret.json`` under CPU
+emulation, ``tpu-v5-lite.json`` on a v5e, ``tpu-v6-lite.json`` on a
+v6e): block sizes timed under interpret-mode emulation say nothing about
+Mosaic behavior, and blocks tuned on one chip generation say little
+about another, so neither is ever served to the wrong run.
 """
 
 from __future__ import annotations
 
 import json
+import logging
 import os
+import re
 import tempfile
 import time
+from pathlib import Path
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -42,7 +47,12 @@ __all__ = [
 Blocks = Tuple[int, int, int]
 
 _ENV_DIR = "REPRO_AUTOTUNE_DIR"
-_DEFAULT_DIR = os.path.join("experiments", "autotune")
+# anchored to the checkout (<checkout>/src/repro/kernels/autotune.py),
+# not to whatever directory the process was started from
+_DEFAULT_DIR = str(Path(__file__).resolve().parents[3]
+                   / "experiments" / "autotune")
+
+_log = logging.getLogger(__name__)
 
 # (store name) -> {key: [bb, bke, bo]}; None = not yet loaded from disk
 _MEM: Dict[str, Optional[Dict[str, list]]] = {}
@@ -71,16 +81,18 @@ def cache_key(kernel: str, b: int, ke: int, o: int, n: int, m: int, dtype,
 
 
 def device_kind() -> str:
-    """Platform actually executing ("cpu", "tpu", ...)."""
-    try:
-        return jax.default_backend()
-    except Exception:  # no devices at all — still allow store reads
-        return "cpu"
+    """Chip actually executing, as JAX names it ("cpu", "TPU v5 lite",
+    ...).  A backend that fails to start raises here — it is never
+    read as the CPU."""
+    return str(jax.devices()[0].device_kind)
 
 
 def _store_name(backend: str) -> str:
-    kind = device_kind()
-    return backend if backend == kind else f"{kind}-{backend}"
+    # "TPU v5 lite" -> "tpu-v5-lite"; the backend suffixes the name only
+    # when it is not the device's own platform (interpret on CPU/TPU)
+    kind = re.sub(r"[^a-z0-9]+", "-", device_kind().lower()).strip("-")
+    platform = jax.devices()[0].platform
+    return kind if backend == platform else f"{kind}-{backend}"
 
 
 def store_path(backend: str) -> str:
@@ -167,8 +179,10 @@ def tune(
             for _ in range(iters):
                 jax.block_until_ready(runner(blocks))
             dt = (time.perf_counter() - t0) / iters
-        except Exception:
-            continue  # candidate failed to compile/run — skip it
+        except Exception as e:  # candidate failed to compile/run
+            _log.warning("autotune %s: candidate blocks %s dropped: %s: %s",
+                         key, tuple(blocks), type(e).__name__, e)
+            continue
         if dt < best_t:
             best, best_t = blocks, dt
     if best is None:

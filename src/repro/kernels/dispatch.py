@@ -10,15 +10,17 @@ reference formulation.
 
 The jnp formulations remain first-class: they are the semantics the
 kernels are tested against, and they are what the engine uses whenever
-kernels don't apply — under ``jax.grad`` (the Pallas bodies carry no VJP
-rules), on CPU by default (interpret-mode Pallas is emulation, not perf),
-or when a shape fails a kernel's tiling constraints.
+kernels don't apply — on the backward pass (the Pallas bodies carry no
+VJP rules, so every kernel call is a ``jax.custom_vjp`` whose backward
+is the reference's VJP), on CPU by default (interpret-mode Pallas is
+emulation, not perf), or when a shape fails a kernel's tiling
+constraints.
 
 Under an installed mesh env the engine no longer surrenders to XLA: when
 the use-site supplies a :class:`ShardSpec` (how TP/FSDP slices the
 (b, ke, o) GEMM), the engine computes the **per-shard local problem**,
 fits blocks against it, and runs the selected Pallas kernel inside
-``jax.experimental.shard_map`` — partial products over a sharded
+``jax.shard_map`` — partial products over a sharded
 contraction dim are combined with ``psum``; an out-dim-sharded GEMM needs
 no collective.  The jnp reference remains the fallback whenever the local
 shape doesn't fit a kernel or a spec slices the N:M metadata axis
@@ -67,7 +69,6 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.interpreters import ad
 from jax.sharding import PartitionSpec as P
 
 from repro.core import nm
@@ -215,6 +216,10 @@ class GemmProblem:
     autotune cache key are all derived from the same problem identity
     and cannot drift.  The legacy ``plan(mode, b=..., ...)`` kwarg
     spelling still works through a warn-once shim.
+
+    ``differentiating`` plans the backward pass of a site (always the
+    jnp reference's VJP — see :func:`_reference_vjp`); the static plan
+    auditor's ``grad`` phase sets it.
 
     ``epilogue`` and ``activation`` are the *canonical point strings*
     (``EpilogueSpec.point`` / ``ActivationSpec.point``), not the operand
@@ -382,13 +387,28 @@ _BB_CAPS = (256, 128, 64, 32)
 _BO_CAPS = (256, 128, 64)
 _BKE_CAPS = (1024, 512, 256, 128)
 
+# Mosaic tiles the second-to-last (sublane) dim of a block in 8-row
+# quanta unless the block spans the whole array dim
+_SUBLANE = 8
+
+
+def _fit_rows(b: int, cap: int) -> Optional[int]:
+    """Row (sublane) block for ``b`` rows: all of them when ``b <= cap``
+    (a block equal to the array dim is always legal), else the largest
+    divisor ``<= cap`` on the 8-row sublane quantum — ``b=200`` tiles as
+    40, not 100, which Mosaic refuses.  ``None`` when no divisor is on
+    the quantum (the engine then falls back to the jnp reference)."""
+    if b <= cap:
+        return b
+    return largest_fitting_block(b, cap, _SUBLANE)
+
 
 def _enumerate(b, ke, o, ke_multiple):
     out = []
     for cb in _BB_CAPS:
         for co in _BO_CAPS:
             for ck in _BKE_CAPS:
-                bb = largest_fitting_block(b, cb)
+                bb = _fit_rows(b, cb)
                 bo = largest_fitting_block(o, co)
                 bke = largest_fitting_block(ke, ck, ke_multiple)
                 if bb and bo and bke and (bb, bke, bo) not in out:
@@ -415,7 +435,7 @@ _Q_SUBLANE = 32
 def _fit_tile_gemm(b, ke, o, n, m, dtype):
     if quant.is_quantized_dtype(dtype):
         return None
-    bb = largest_fitting_block(b, 128)
+    bb = _fit_rows(b, 128)
     bo = largest_fitting_block(o, 128)
     bke = largest_fitting_block(ke, 512)
     if bb is None or bo is None or bke is None:
@@ -462,7 +482,7 @@ def _nm_ke_multiple(n: int) -> int:
 def _fit_nm_spmm(b, ke, o, n, m, dtype):
     if m != 4 or quant.is_quantized_dtype(dtype):
         return None  # kernel fixes M=4 (paper's detailed design)
-    bb = largest_fitting_block(b, 128)
+    bb = _fit_rows(b, 128)
     bo = largest_fitting_block(o, 128)
     bke = largest_fitting_block(ke, 512, _nm_ke_multiple(n))
     if bb is None or bo is None or bke is None:
@@ -491,7 +511,7 @@ def _run_nm_spmm(x2, params, cfg, g, blocks, interpret, out_dtype,
 def _fit_nm_gather(b, ke, o, n, m, dtype):
     if m != 4 or quant.is_quantized_dtype(dtype):
         return None
-    bb = largest_fitting_block(b, 128)
+    bb = _fit_rows(b, 128)
     bo = largest_fitting_block(o, 128)
     # kernel reshapes the activation tile into 4-row blocks: block_ke % 4 == 0
     bke = largest_fitting_block(ke, 512, 4)
@@ -975,8 +995,8 @@ registry.register(KernelEntry(
 # the same registry/plan machinery as the GEMMs.
 
 def _fit_flash(b, ke, o, n, m, dtype):
-    bq = largest_fitting_block(b, 256)
-    bk = largest_fitting_block(ke, 256)
+    bq = _fit_rows(b, 256)
+    bk = _fit_rows(ke, 256)
     if bq is None or bk is None or o % 8 != 0:
         return None
     return (bq, bk, o)
@@ -986,8 +1006,8 @@ def _flash_candidates(b, ke, o, n, m, dtype):
     out = []
     for cq in (256, 128):
         for ck in (256, 128):
-            bq = largest_fitting_block(b, cq)
-            bk = largest_fitting_block(ke, ck)
+            bq = _fit_rows(b, cq)
+            bk = _fit_rows(ke, ck)
             if bq and bk and (bq, bk, o) not in out:
                 out.append((bq, bk, o))
     return out
@@ -1039,9 +1059,20 @@ def input_features(params: Dict[str, Any], cfg) -> int:
     return params["values"].shape[0] * cfg.m // cfg.n
 
 
-def _under_autodiff(*trees) -> bool:
-    return any(isinstance(leaf, ad.JVPTracer)
-               for leaf in jax.tree_util.tree_leaves(trees))
+def _reference_vjp(kernel_fn: Callable, reference_fn: Callable, *operands):
+    """``kernel_fn(*operands)``, differentiated as ``reference_fn``.
+
+    Pallas bodies carry no VJP rules, so each kernel call is a
+    ``jax.custom_vjp`` at the kernel boundary: the forward pass runs the
+    kernel, the backward pass is the VJP of the jnp reference
+    formulation on the same operands — the AUTODIFF fallback that
+    ``GemmProblem.differentiating`` plans.  ``operands`` are pytrees of
+    arrays; both functions close over static values only.
+    """
+    f = jax.custom_vjp(kernel_fn)
+    f.defvjp(lambda *ops: (kernel_fn(*ops), ops),
+             lambda ops, ct: jax.vjp(reference_fn, *ops)[1](ct))
+    return f(*operands)
 
 
 _mesh_probe_warned = False
@@ -1567,7 +1598,7 @@ def _shard_map_runner(
     the accumulator dtype, and the gathered result is dequantized once.
     Float entries psum fp32 partials before the output cast, as before.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     x_spec = P(shard.batch, shard.ke)
     p_specs = _shard_param_specs(mode, shard, params)
@@ -1602,7 +1633,7 @@ def _shard_map_runner(
         return y.astype(out_dtype)
 
     return shard_map(body, mesh=shard.mesh, in_specs=(x_spec, p_specs),
-                     out_specs=out_spec, check_rep=False)
+                     out_specs=out_spec, check_vma=False)
 
 
 def sparse_matmul(
@@ -1686,7 +1717,6 @@ def sparse_matmul(
 
     problem = GemmProblem(
         mode, b=b, ke=ke, o=o, n=cfg.n, m=cfg.m, dtype=exec_dtype,
-        differentiating=_under_autodiff(x2, params),
         sharded=False if local else _mesh_active(),
         shard=shard,
         static_scales=quant.has_static_scales(params),
@@ -1713,6 +1743,16 @@ def sparse_matmul(
     interpret = decision.backend == "interpret"
     blocks = decision.blocks
     out_dt = jnp.float32 if pre_q else x2.dtype
+    epi_ops = ((epilogue.bias, epilogue.requant_scale)
+               if epilogue is not None else None)
+
+    def _with_epilogue(ops):
+        return None if ops is None else epilib.Epilogue(epilogue.spec, *ops)
+
+    def reference(x2_, params_, ops):
+        y = _JNP_IMPL[mode](x2_, params_, cfg, g)
+        ep = _with_epilogue(ops)
+        return y if ep is None else epilib.apply_reference(y, ep)
 
     if decision.uses_shard_map:
         lb, lke, lo = decision.local_dims
@@ -1729,10 +1769,13 @@ def sparse_matmul(
                                   key=key, persist=dcfg.persist_autotune)
             if tuned is not None:
                 blocks = tuned
-        y2 = _shard_map_runner(entry, mode, cfg, shard, blocks, interpret,
-                               out_dt, params)(x2, params)
-        if epilogue is not None:  # psum happened inside: apply unfused
-            y2 = epilib.apply_reference(y2, epilogue)
+        def run_sharded(x2_, params_, ops):
+            y = _shard_map_runner(entry, mode, cfg, shard, blocks, interpret,
+                                  out_dt, params_)(x2_, params_)
+            ep = _with_epilogue(ops)  # psum happened inside: apply unfused
+            return y if ep is None else epilib.apply_reference(y, ep)
+
+        y2 = _reference_vjp(run_sharded, reference, x2, params, epi_ops)
         return y2.reshape(*lead, o)
 
     fused_epi = epilogue if decision.epilogue_fused else None
@@ -1757,10 +1800,16 @@ def sparse_matmul(
         if tuned is not None:
             blocks = tuned
 
-    y2 = entry.run(x2, params, cfg, g, blocks, interpret, out_dt,
-                   epilogue=fused_epi, **act_kw)
-    if epilogue is not None and fused_epi is None:
-        y2 = epilib.apply_reference(y2, epilogue)
+    def run_single(x2_, params_, ops):
+        ep = _with_epilogue(ops)
+        fused = ep if decision.epilogue_fused else None
+        y = entry.run(x2_, params_, cfg, g, blocks, interpret, out_dt,
+                      epilogue=fused, **act_kw)
+        if ep is not None and fused is None:
+            y = epilib.apply_reference(y, ep)
+        return y
+
+    y2 = _reference_vjp(run_single, reference, x2, params, epi_ops)
     return y2.reshape(*lead, o)
 
 
@@ -1935,7 +1984,6 @@ def gate_up_matmul(
             GemmProblem(
                 mode_g, b=b, ke=ke, o=o, n=cfg.n, m=cfg.m,
                 dtype=qdt or x2.dtype,
-                differentiating=_under_autodiff(x2, params_g, params_u),
                 sharded=False if local else _mesh_active(), shard=shard,
                 static_scales=quant.has_static_scales(params_g),
                 epilogue=spec.point, dual=True,
@@ -1947,9 +1995,20 @@ def gate_up_matmul(
         interpret = decision.backend == "interpret"
         pre_q = quant.is_quantized_dtype(x2.dtype)
         out_dt = jnp.float32 if pre_q else x2.dtype
-        y2 = entry.run_dual(x2, params_g, params_u, cfg, g,
-                            decision.blocks, interpret, out_dt,
-                            epilogue=epi)
+
+        def run_dual(x2_, pg, pu, rq_scale):
+            return entry.run_dual(
+                x2_, pg, pu, cfg, g, decision.blocks, interpret, out_dt,
+                epilogue=epilib.Epilogue(spec, requant_scale=rq_scale))
+
+        def reference_dual(x2_, pg, pu, rq_scale):
+            y_g = _JNP_IMPL[mode_g](x2_, pg, cfg, g)
+            y_u = _JNP_IMPL[mode_g](x2_, pu, cfg, g)
+            h = jax.nn.silu(y_g.astype(jnp.float32)) * y_u.astype(jnp.float32)
+            return h.astype(out_dt)
+
+        y2 = _reference_vjp(run_dual, reference_dual, x2, params_g, params_u,
+                            epi.requant_scale)
         return y2.reshape(*lead, o)
 
     # the concat collapse (one GEMM over 2o, activation read once from
@@ -1991,9 +2050,10 @@ def attention(
     On a kernel backend the registry's ``flash_attention`` Pallas entry
     runs (self-attention shapes only: Tq == Tk, no query offset); the jnp
     chunked online-softmax formulation with its recompute-from-LSE custom
-    VJP remains the reference and the fallback — under autodiff, under a
-    mesh env (attention sharding is head-parallel and XLA already keeps it
-    collective-free), or when a shape fails the tiling constraints.
+    VJP remains the reference, the backward pass of the kernel call, and
+    the fallback — under a mesh env (attention sharding is head-parallel
+    and XLA already keeps it collective-free), or when a shape fails the
+    tiling constraints.
     """
     from repro.models.attention import chunked_attention  # local: avoid cycle
 
@@ -2002,9 +2062,7 @@ def attention(
     tk = k.shape[1]
     decision = plan(
         GemmProblem("attention", b=tq, ke=tk, o=d, n=4, m=4,
-                    dtype=qg.dtype,
-                    differentiating=_under_autodiff(qg, k, v),
-                    sharded=_mesh_active()),
+                    dtype=qg.dtype, sharded=_mesh_active()),
         dispatch=dcfg,
     )
     if not decision.uses_kernel or tq != tk or q_offset != 0:
@@ -2012,12 +2070,19 @@ def attention(
                                  p_bf16, s_bf16)
     entry = _entry_by_name("attention", decision.kernel)
     interpret = decision.backend == "interpret"
-    # (B, Hkv, G, T, D) -> (B, Hq, T, D); Hq = Hkv*G flattening matches the
-    # wrapper's jnp.repeat KV-head expansion order
-    q4 = qg.reshape(b, hkv * grp, tq, d)
-    k4 = k.transpose(0, 2, 1, 3)
-    v4 = v.transpose(0, 2, 1, 3)
-    out = entry.run(None, {"q": q4, "k": k4, "v": v4},
-                    types.SimpleNamespace(causal=causal), None,
-                    decision.blocks, interpret, qg.dtype)
-    return out.reshape(b, hkv, grp, tq, d)
+
+    def run_flash(qg_, k_, v_):
+        # (B, Hkv, G, T, D) -> (B, Hq, T, D); Hq = Hkv*G flattening matches
+        # the wrapper's jnp.repeat KV-head expansion order
+        out = entry.run(None, {"q": qg_.reshape(b, hkv * grp, tq, d),
+                               "k": k_.transpose(0, 2, 1, 3),
+                               "v": v_.transpose(0, 2, 1, 3)},
+                        types.SimpleNamespace(causal=causal), None,
+                        decision.blocks, interpret, qg.dtype)
+        return out.reshape(b, hkv, grp, tq, d)
+
+    def reference(qg_, k_, v_):
+        return chunked_attention(qg_, k_, v_, causal, chunk, q_offset,
+                                 p_bf16, s_bf16)
+
+    return _reference_vjp(run_flash, reference, qg, k, v)

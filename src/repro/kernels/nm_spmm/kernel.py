@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.epilogue import (
     EpilogueSpec, flush_tile, out_dtype_for, tile_in_specs, tile_operands,
 )
@@ -54,20 +53,22 @@ def _decompress_tile(v: jax.Array, idx: jax.Array, n: int) -> jax.Array:
     The in-VMEM "M:1 mux": slot j of each block receives the value whose
     2-bit index equals j.  Indices are unique within a block, so the sum
     over the N kept slots has at most one nonzero term per position and is
-    exact in bf16.
+    exact in bf16.  int8 values are muxed in int32 (Mosaic's vector
+    integer ops take i16/i32 only) and narrowed back for the int8 MXU dot.
     """
     bkc, bo = v.shape
     nb = bkc // n
     bke = nb * 4
+    mux_dt = jnp.int32 if jnp.issubdtype(v.dtype, jnp.integer) else v.dtype
     j_pat = jax.lax.broadcasted_iota(jnp.int32, (bke, bo), 0) % 4
-    v3 = v.reshape(nb, n, bo)
+    v3 = v.astype(mux_dt).reshape(nb, n, bo)
     i3 = idx.reshape(nb, n, bo)
-    out = jnp.zeros((bke, bo), v.dtype)
+    out = jnp.zeros((bke, bo), mux_dt)
     for s in range(n):
         vs = _expand_rows4(v3[:, s, :])
         ix = _expand_rows4(i3[:, s, :])
         out = out + jnp.where(ix == j_pat, vs, jnp.zeros_like(vs))
-    return out
+    return out.astype(v.dtype)
 
 
 def _spmm_accumulate(x_ref, v_ref, pm_ref, acc_ref, n: int, acc_dtype):
@@ -166,7 +167,7 @@ def nm_spmm(
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
         scratch_shapes=[pltpu.VMEM((block_b, block_o), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -226,7 +227,7 @@ def _nm_spmm_quantized(
             out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, kk: (i, j)),
             out_shape=jax.ShapeDtypeStruct((b, o), acc_dtype),
             scratch_shapes=[pltpu.VMEM((block_b, block_o), acc_dtype)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -245,7 +246,7 @@ def _nm_spmm_quantized(
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
         scratch_shapes=[pltpu.VMEM((block_b, block_o), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -384,7 +385,7 @@ def nm_spmm_masked(
                                           epi=epi),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -496,7 +497,7 @@ def nm_spmm_dual(
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
         scratch_shapes=[pltpu.VMEM((block_b, block_o), acc_dtype),
                         pltpu.VMEM((block_b, block_o), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.epilogue import (
     EpilogueSpec, flush_tile, out_dtype_for, tile_in_specs, tile_operands,
 )
@@ -118,7 +117,7 @@ def nm_spmm_gather(
         out_specs=pl.BlockSpec((block_o, block_b), lambda i, j, kk: (j, i)),
         out_shape=jax.ShapeDtypeStruct((o, b), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_o, block_b), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -182,7 +181,7 @@ def _nm_spmm_gather_quantized(
             out_specs=pl.BlockSpec((block_o, block_b), lambda i, j, kk: (j, i)),
             out_shape=jax.ShapeDtypeStruct((o, b), acc_dtype),
             scratch_shapes=[pltpu.VMEM((block_o, block_b), acc_dtype)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -201,7 +200,7 @@ def _nm_spmm_gather_quantized(
         out_specs=pl.BlockSpec((block_o, block_b), lambda i, j, kk: (j, i)),
         out_shape=jax.ShapeDtypeStruct((o, b), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_o, block_b), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -387,7 +386,7 @@ def nm_spmm_gather_bk(
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
         scratch_shapes=[pltpu.VMEM((block_o, block_b), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -519,7 +518,7 @@ def nm_spmm_gather_bk_masked(
                                                quant=quant, epi=epi),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -627,7 +626,7 @@ def nm_spmm_gather_dual_bk(
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
         scratch_shapes=[pltpu.VMEM((block_o, block_b), acc_dtype),
                         pltpu.VMEM((block_o, block_b), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -176,16 +176,14 @@ def detect_backend() -> str:
 
     Interpret-mode Pallas is emulation, not a perf path, so it is never
     auto-selected — tests and parity checks opt in explicitly (via the
-    ``REPRO_KERNEL_BACKEND`` env var or a DispatchConfig override).
+    ``REPRO_KERNEL_BACKEND`` env var or a DispatchConfig override).  A
+    backend that fails to start raises from ``jax.default_backend()``:
+    it is never read as the CPU.
     """
     env = os.environ.get(_ENV_BACKEND, "").strip().lower()
     if env in ("tpu", "interpret", "jnp"):
         return env
-    try:
-        platform = jax.default_backend()
-    except Exception:  # no devices at all — reference path still works
-        platform = "cpu"
-    return "tpu" if platform == "tpu" else "jnp"
+    return "tpu" if jax.default_backend() == "tpu" else "jnp"
 
 
 def resolve_backend(requested: str = "auto") -> str:
@@ -225,13 +223,7 @@ def fp8_native_dot() -> bool:
         return True
     if env in ("0", "false", "no"):
         return False
-    try:
-        devices = jax.devices()
-    except Exception:
-        return False
-    if not devices:
-        return False
-    kind = str(getattr(devices[0], "device_kind", "")).lower()
+    kind = jax.devices()[0].device_kind.lower()
     return any(tag in kind for tag in _FP8_TPU_KINDS)
 
 

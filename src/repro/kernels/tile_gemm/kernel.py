@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.epilogue import (
     EpilogueSpec, flush_tile, out_dtype_for, tile_in_specs, tile_operands,
 )
@@ -112,7 +111,7 @@ def tile_gemm(
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
         scratch_shapes=[pltpu.VMEM((block_b, block_o), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -170,7 +169,7 @@ def _tile_gemm_quantized(
             out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, kk: (i, j)),
             out_shape=jax.ShapeDtypeStruct((b, o), acc_dtype),
             scratch_shapes=[pltpu.VMEM((block_b, block_o), acc_dtype)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -188,7 +187,7 @@ def _tile_gemm_quantized(
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
         scratch_shapes=[pltpu.VMEM((block_b, block_o), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -325,7 +324,7 @@ def tile_gemm_masked(
                                           quant=quant, epi=epi),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -438,7 +437,7 @@ def tile_gemm_dual(
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype_for(epi, out_dtype)),
         scratch_shapes=[pltpu.VMEM((block_b, block_o), acc_dtype),
                         pltpu.VMEM((block_b, block_o), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -106,7 +106,10 @@ def main():
 
     from repro import serving
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import init_params
+
+    use_compile_cache()
 
     mesh = None
     if args.mesh:

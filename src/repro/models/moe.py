@@ -34,7 +34,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.sparse_linear import (
     SparsityConfig, apply_gate_up, apply_linear, init_linear)
@@ -292,7 +292,7 @@ def _moe_shardmap(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
         mesh=mesh,
         in_specs=(P(), expert_specs, x_spec),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )(p["router"], experts, x)
 
 

@@ -250,10 +250,10 @@ def prepare(
         if cfg is None:
             raise ValueError("mesh placement needs cfg= (sharding rules "
                              "are model-config driven)")
-        from repro.launch.mesh import make_axis_env
+        from repro.launch.mesh import make_axis_env, make_mesh
         from repro.launch.shardings import ShardingRules
         d_, m_ = spec.mesh
-        mesh = jax.make_mesh((d_, m_), ("data", "model"))
+        mesh = make_mesh((d_, m_), ("data", "model"))
         axis_env = make_axis_env(mesh)
         rules = ShardingRules(axis_env, cfg)
         params = jax.device_put(params, rules.tree_shardings(params))

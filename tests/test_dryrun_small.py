@@ -30,14 +30,14 @@ def test_small_mesh_train_step_shards_and_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_axis_env
+        from repro.launch.mesh import make_axis_env, make_mesh
         from repro.launch.shardings import ShardingRules
         from repro.models import make_train_step
         from repro.models.lm import init_train_state
         from repro.models.pjit_utils import use_axis_env
 
         cfg = get_smoke_config("internlm2_1_8b")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         env = make_axis_env(mesh)
         rules = ShardingRules(env, cfg)
         params, opt = init_train_state(jax.random.PRNGKey(0), cfg)
@@ -71,13 +71,13 @@ def test_small_mesh_moe_shardmap():
     out = _run(textwrap.dedent("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_axis_env
+        from repro.launch.mesh import make_axis_env, make_mesh
         from repro.models.moe import apply_moe, init_moe
         from repro.models.pjit_utils import use_axis_env
 
         cfg = get_smoke_config("qwen3_moe_235b_a22b")
         cfg = dataclasses.replace(cfg, moe_capacity_factor=16.0)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         env = make_axis_env(mesh)
         p = init_moe(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
@@ -102,10 +102,10 @@ def test_sharded_dispatch_parity_subprocess():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import SparsityConfig, apply_linear, init_linear
         from repro.kernels import dispatch
-        from repro.launch.mesh import make_axis_env
+        from repro.launch.mesh import make_axis_env, make_mesh
         from repro.models.pjit_utils import use_axis_env
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         env = make_axis_env(mesh)
         for mode, n, hint in [("dense", 4, "col"), ("compressed", 2, "row"),
                               ("compressed", 1, "col"), ("gather", 2, "row")]:
@@ -137,7 +137,7 @@ def test_hlo_cost_flops_vs_analytic():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_axis_env
+        from repro.launch.mesh import make_axis_env, make_mesh
         from repro.launch.shardings import ShardingRules
         from repro.launch.hlo_cost import analyze
         from repro.models import make_train_step
@@ -149,7 +149,7 @@ def test_hlo_cost_flops_vs_analytic():
         cfg = dataclasses.replace(cfg, num_layers=4, d_model=128, d_ff=512,
                                   num_heads=4, num_kv_heads=4, head_dim=32,
                                   vocab_size=512)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         env = make_axis_env(mesh)
         rules = ShardingRules(env, cfg)
         params, opt = init_train_state(jax.random.PRNGKey(0), cfg)

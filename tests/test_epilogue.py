@@ -297,8 +297,9 @@ def test_gate_up_quantized_fused_matches_singles(family, qdtype):
 
 
 def test_gate_up_grad_falls_back_and_reads_x_once():
-    """Under autodiff the dual kernel declines to the jnp tier, which
-    runs the pair as two plain GEMMs (value parity with the reference)."""
+    """Under autodiff the dual kernel runs forward and its backward pass
+    is the VJP of the jnp tier's two plain GEMMs (value parity with the
+    reference)."""
     pg, pu = {"w": _w(seed=1)}, {"w": _w(seed=2)}
     cfg = _cfg("dense", 4)
     x = _x()

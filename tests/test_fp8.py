@@ -376,11 +376,11 @@ def sharded(fn):
 
 @pytest.fixture(scope="module")
 def env():
-    from repro.launch.mesh import make_axis_env
+    from repro.launch.mesh import make_axis_env, make_mesh
 
     if jax.device_count() < 8:
         pytest.skip("needs 8 forced host devices")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     return make_axis_env(mesh)
 
 

@@ -174,7 +174,7 @@ def test_paged_decode_parity_under_tp_mesh_subprocess():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_axis_env
+        from repro.launch.mesh import make_axis_env, make_mesh
         from repro.models import init_params
         from repro.models.pjit_utils import use_axis_env
         from test_serving import _paged_logits
@@ -184,7 +184,7 @@ def test_paged_decode_parity_under_tp_mesh_subprocess():
         params = init_params(jax.random.PRNGKey(0), cfg)
         tokens = [3, 17, 9, 41, 5]
         ref, ref_gen = _paged_logits(params, cfg, tokens, 3, chunks=(2,))
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        mesh = make_mesh((1, 8), ("data", "model"))
         with use_axis_env(make_axis_env(mesh)):
             got, got_gen = _paged_logits(params, cfg, tokens, 3,
                                          chunks=(2,))

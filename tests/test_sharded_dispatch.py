@@ -26,9 +26,9 @@ pytestmark = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def env():
-    from repro.launch.mesh import make_axis_env
+    from repro.launch.mesh import make_axis_env, make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     return make_axis_env(mesh)
 
 
