@@ -1,0 +1,61 @@
+"""The system under test: the repro serving stack, stood up for one cell.
+
+Weights come from the cell's reference module (the same seeded bfloat16
+values the reference reads), are put into the program's parameter tree
+by the family's adapter (``bench/adapters/<family>.py``), in the layout
+the configuration serves, in one jitted call on the device, and go
+through ``repro.serving.prepare``.  ``Engine.run`` is the
+only entry the window drives.
+"""
+
+from __future__ import annotations
+
+from typing import List, Sequence
+
+import jax
+
+
+def serving_spec(cfg: dict, mix: dict, *, control: str = None,
+                 backend: str = "auto"):
+    from repro.serving import ServingSpec
+
+    serve = cfg["program"]
+    sparsity = serve.get("sparsity")
+    eng = mix["engine"]
+    return ServingSpec(
+        layout=serve["layout"],
+        sparsity=None if sparsity is None else tuple(sparsity),
+        qdtype=control if control is not None else serve.get("qdtype"),
+        kv_qdtype=serve.get("kv_qdtype"), backend=backend,
+        slots=eng["slots"], max_len=eng["max_len"],
+        block_len=eng["block_len"], prefill_chunk=eng["prefill_chunk"])
+
+
+def to_requests(drawn: Sequence) -> List:
+    from repro.serving import Request
+
+    return [Request(rid=r.rid, prompt=r.prompt,
+                    max_new_tokens=r.max_new_tokens, arrival=r.arrival)
+            for r in drawn]
+
+
+class Served:
+    """The prepared model and its engine for one run."""
+
+    def __init__(self, ref, adapter, seed: int, cfg: dict, mix: dict, *,
+                 control: str = None, backend: str = "auto"):
+        from repro import serving
+
+        self.spec = serving_spec(cfg, mix, control=control, backend=backend)
+        self.model_cfg = self.spec.apply_to(adapter.model_config(cfg))
+        params = adapter.program_params(ref, seed, cfg)
+        self.prepared = serving.prepare(params, self.spec, cfg=self.model_cfg)
+        del params
+        jax.block_until_ready(self.prepared.params)
+        self.engine = serving.Engine(self.prepared)
+
+    def weight_bytes(self) -> int:
+        return sum(x.nbytes for x in jax.tree.leaves(self.prepared.params))
+
+    def run(self, drawn: Sequence):
+        return self.engine.run(to_requests(drawn))
