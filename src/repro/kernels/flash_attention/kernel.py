@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.registry import kernel_label
+
 
 NEG_INF = -1e30
 
@@ -119,4 +121,5 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("flash_attention", "flash_attention"),
     )(q, k, v)

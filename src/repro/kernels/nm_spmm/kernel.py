@@ -29,6 +29,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.epilogue import (
     EpilogueSpec, flush_tile, out_dtype_for, tile_in_specs, tile_operands,
 )
+from repro.kernels.registry import kernel_label
 
 _IDENT = EpilogueSpec()
 
@@ -171,6 +172,7 @@ def nm_spmm(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("nm_spmm", "nm_spmm", values.dtype),
     )(x, values, meta_packed, *tile_operands(epi, bias, requant_scale, o))
 
 
@@ -231,6 +233,7 @@ def _nm_spmm_quantized(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
+            **kernel_label("nm_spmm_raw", "nm_spmm", values.dtype),
         )(x_q, values, meta_packed)
     return pl.pallas_call(
         lambda *refs: _spmm_kernel(*refs, n=n, nk=nk, acc_dtype=acc_dtype,
@@ -250,6 +253,7 @@ def _nm_spmm_quantized(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("nm_spmm", "nm_spmm", values.dtype),
     )(x_q, values, meta_packed, x_scale, w_scale,
       *tile_operands(epi, bias, requant_scale, o))
 
@@ -389,6 +393,7 @@ def nm_spmm_masked(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("nm_spmm_masked", "nm_spmm", values.dtype),
     )(kmap, kmask, *operands)
 
 
@@ -501,6 +506,7 @@ def nm_spmm_dual(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("nm_spmm_dual", "nm_spmm", values_g.dtype),
     )(*operands)
 
 

@@ -25,6 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.epilogue import (
     EpilogueSpec, flush_tile, out_dtype_for, tile_in_specs, tile_operands,
 )
+from repro.kernels.registry import kernel_label
 
 _IDENT = EpilogueSpec()
 
@@ -121,6 +122,8 @@ def nm_spmm_gather(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("nm_spmm_gather", "nm_spmm_gather",
+                       values.dtype),
     )(x_t, values, idx)
 
 
@@ -185,6 +188,8 @@ def _nm_spmm_gather_quantized(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
+            **kernel_label("nm_spmm_gather_raw", "nm_spmm_gather",
+                           values.dtype),
         )(x_t, values, idx)
     return pl.pallas_call(
         lambda xr, vr, ir, xsr, wsr, orf, acc: _gather_q_kernel(
@@ -204,6 +209,8 @@ def _nm_spmm_gather_quantized(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("nm_spmm_gather", "nm_spmm_gather",
+                       values.dtype),
     )(x_t, values, idx, x_scale, w_scale)
 
 
@@ -390,6 +397,8 @@ def nm_spmm_gather_bk(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("nm_spmm_gather_bk", "nm_spmm_gather",
+                       values.dtype),
     )(*operands)
 
 
@@ -522,6 +531,8 @@ def nm_spmm_gather_bk_masked(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("nm_spmm_gather_bk_masked", "nm_spmm_gather",
+                       values.dtype),
     )(kmap, kmask, *operands)
 
 
@@ -630,4 +641,6 @@ def nm_spmm_gather_dual_bk(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("nm_spmm_gather_dual_bk", "nm_spmm_gather",
+                       values_g.dtype),
     )(*operands)

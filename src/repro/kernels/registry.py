@@ -48,6 +48,7 @@ __all__ = [
     "dtype_name",
     "fp8_native_dot",
     "supports_fp8",
+    "kernel_label",
     "KERNEL_BACKENDS",
 ]
 
@@ -112,6 +113,22 @@ class KernelEntry:
 
 
 _REGISTRY: Dict[str, List[KernelEntry]] = {}
+
+# storage dtype of the weight values -> suffix of the quantized entries
+_ENTRY_SUFFIX = {"int8": "_int8", "float8_e4m3fn": "_fp8"}
+
+
+def kernel_label(name: str, family: str, values_dtype=None) -> dict:
+    """``pallas_call`` keywords that name a kernel in HLO text and in
+    profiler traces: ``name`` is the kernel's form (``nm_spmm_dual``),
+    and ``metadata`` records the registry entry that runs it — ``family``
+    on float weights, ``family_int8`` / ``family_fp8`` on quantized
+    ones — as ``kernel_metadata={"kernel":"nm_spmm_int8"}``."""
+    entry = family
+    if values_dtype is not None:
+        entry += _ENTRY_SUFFIX.get(jax.numpy.dtype(values_dtype).name, "")
+    return {"name": name, "metadata": {"kernel": entry}}
+
 
 
 def register(entry: KernelEntry) -> KernelEntry:

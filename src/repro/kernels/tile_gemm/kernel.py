@@ -26,6 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.epilogue import (
     EpilogueSpec, flush_tile, out_dtype_for, tile_in_specs, tile_operands,
 )
+from repro.kernels.registry import kernel_label
 
 _IDENT = EpilogueSpec()
 
@@ -115,6 +116,7 @@ def tile_gemm(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("tile_gemm", "tile_gemm", w.dtype),
     )(x, w, *tile_operands(epi, bias, requant_scale, o))
 
 
@@ -173,6 +175,7 @@ def _tile_gemm_quantized(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
+            **kernel_label("tile_gemm_raw", "tile_gemm", w_q.dtype),
         )(x_q, w_q)
     return pl.pallas_call(
         lambda *refs: _gemm_kernel(*refs, nk=nk, acc_dtype=acc_dtype,
@@ -191,6 +194,7 @@ def _tile_gemm_quantized(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("tile_gemm", "tile_gemm", w_q.dtype),
     )(x_q, w_q, x_scale, w_scale,
       *tile_operands(epi, bias, requant_scale, o))
 
@@ -328,6 +332,7 @@ def tile_gemm_masked(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("tile_gemm_masked", "tile_gemm", w.dtype),
     )(kmap, kmask, *operands)
 
 
@@ -441,6 +446,7 @@ def tile_gemm_dual(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **kernel_label("tile_gemm_dual", "tile_gemm", w_g.dtype),
     )(*operands)
 
 

@@ -53,7 +53,7 @@ def run_lockstep(prepared: Prepared, requests: Sequence[Request],
     # latency must include queue wait so the gated p50/p99 rows compare
     # the same enqueue->done definition the Engine reports
     arrive_wall = {}
-    pos = 0
+    pos = steps = 0
     t0 = time.perf_counter()
 
     with prepared.activate():
@@ -73,7 +73,8 @@ def run_lockstep(prepared: Prepared, requests: Sequence[Request],
                                    None)
                     if nxt_req is not None:
                         slots[s] = {"req": nxt_req, "i": 0, "out": [],
-                                    "wall": arrive_wall[nxt_req.rid]}
+                                    "wall": arrive_wall[nxt_req.rid],
+                                    "admit": now_wall, "first": None}
             if not any(slots) and ai < n:
                 pos += 1     # idle step waiting for an arrival
                 continue
@@ -90,6 +91,7 @@ def run_lockstep(prepared: Prepared, requests: Sequence[Request],
                                   jnp.asarray(feed, jnp.int32)[:, None],
                                   jnp.int32(pos), cfg=cfg)
             nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            steps += 1
             for s in range(batch):
                 a = slots[s]
                 if a is None:
@@ -97,6 +99,8 @@ def run_lockstep(prepared: Prepared, requests: Sequence[Request],
                 a["i"] += 1
                 if a["i"] >= len(a["req"].prompt):
                     a["out"].append(int(nxt[s]))
+                    if a["first"] is None:
+                        a["first"] = time.perf_counter()
                 if len(a["out"]) >= a["req"].max_new_tokens:
                     done_wall = time.perf_counter()
                     lat = done_wall - a["wall"]
@@ -105,7 +109,8 @@ def run_lockstep(prepared: Prepared, requests: Sequence[Request],
                         new_tokens=len(a["out"]),
                         tokens=tuple(a["out"]) if collect_tokens else (),
                         arrival=a["req"].arrival, done_iter=pos,
-                        latency_s=lat,
+                        latency_s=lat, queue_s=a["admit"] - a["wall"],
+                        ttft_s=a["first"] - a["wall"],
                         tokens_per_s=len(a["out"]) / lat if lat > 0 else 0.0))
                     slots[s] = None
             pos += 1
@@ -115,4 +120,4 @@ def run_lockstep(prepared: Prepared, requests: Sequence[Request],
         total=n, completed=len(stats),
         wall_s=time.perf_counter() - t0,
         model_calls=pos, prefill_chunks=0, decode_calls=pos,
-        evictions=0, max_blocks_in_use=0, num_blocks=0)
+        iterations=steps, evictions=0, max_blocks_in_use=0, num_blocks=0)

@@ -55,6 +55,8 @@ class RequestStats:
     arrival: float          # scheduler-iteration timestamp
     done_iter: int
     latency_s: float        # wall: enqueue -> last token
+    queue_s: float          # wall: enqueue -> first admission
+    ttft_s: float           # wall: enqueue -> first token
     tokens_per_s: float     # generated tokens / latency
 
 
@@ -69,6 +71,7 @@ class ServingReport:
     model_calls: int        # prefill chunks + decode steps (lockstep: steps)
     prefill_chunks: int
     decode_calls: int
+    iterations: int         # work iterations (one engine.iter span each)
     evictions: int
     max_blocks_in_use: int
     num_blocks: int
@@ -97,11 +100,15 @@ class ServingReport:
         return self.completed / self.model_calls if self.model_calls else 0.0
 
     def describe(self) -> str:
+        queue = percentile([s.queue_s for s in self.stats], 95.0)
+        ttft = percentile([s.ttft_s for s in self.stats], 95.0)
         return (f"{self.completed}/{self.total} requests in "
                 f"{self.wall_s:.2f}s over {self.model_calls} model calls "
                 f"({self.tokens_per_s:.1f} tok/s, "
                 f"p50 {self.p50_latency_s * 1e3:.0f}ms / "
                 f"p99 {self.p99_latency_s * 1e3:.0f}ms, "
+                f"p95 queue {queue * 1e3:.0f}ms / "
+                f"p95 first token {ttft * 1e3:.0f}ms, "
                 f"{self.evictions} eviction(s), "
                 f"peak {self.max_blocks_in_use}/{self.num_blocks} blocks)")
 
@@ -150,7 +157,25 @@ class Engine:
 
     def run(self, requests: Sequence[Request], *, max_iters: Optional[int] = None,
             collect_tokens: bool = True) -> ServingReport:
+        """Serve ``requests`` to completion.
+
+        Each phase of the loop is a ``jax.profiler.TraceAnnotation``
+        span, on the device trace's clock when a profiler trace is
+        running (about a microsecond each when none is): ``engine.run``
+        holds one ``engine.iter`` per work iteration, which holds
+        ``engine.admit``, ``engine.prefill`` (with its ``engine.sync``
+        on the first token), ``engine.decode_feed``, ``engine.dispatch``
+        and ``engine.sync``; ``engine.retire`` marks each retirement.
+        Spans of one request carry its ``rid``.
+        """
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation("engine.run", requests=len(requests)):
+            return self._serve(requests, max_iters, collect_tokens)
+
+    def _serve(self, requests, max_iters, collect_tokens) -> ServingReport:
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
 
         from repro.models.paged import (paged_decode_step, paged_prefill_chunk,
                                         reset_slot_state)
@@ -177,68 +202,63 @@ class Engine:
         t0 = time.perf_counter()
 
         def _retire(s: int):
-            st = sched.retire(s)
-            now = time.perf_counter()
-            lat = now - st.enqueue_wall
-            stats.append(RequestStats(
-                rid=st.req.rid, prompt_len=len(st.req.prompt),
-                new_tokens=len(st.out),
-                tokens=tuple(st.out) if collect_tokens else (),
-                arrival=st.req.arrival, done_iter=it,
-                latency_s=lat,
-                tokens_per_s=len(st.out) / lat if lat > 0 else 0.0))
+            with TraceAnnotation("engine.retire", rid=sched.slots[s].req.rid):
+                st = sched.retire(s)
+                now = time.perf_counter()
+                lat = now - st.enqueue_wall
+                stats.append(RequestStats(
+                    rid=st.req.rid, prompt_len=len(st.req.prompt),
+                    new_tokens=len(st.out),
+                    tokens=tuple(st.out) if collect_tokens else (),
+                    arrival=st.req.arrival, done_iter=it,
+                    latency_s=lat,
+                    queue_s=st.admit_wall - st.enqueue_wall,
+                    ttft_s=st.first_token_wall - st.enqueue_wall,
+                    tokens_per_s=len(st.out) / lat if lat > 0 else 0.0))
 
-        with self.prepared.activate():
-            while len(stats) < n:
-                # guard on WORK iterations, not the simulated clock:
-                # idle fast-forwarding jumps `it` to absolute arrival
-                # timestamps, which a sparse trace can push past any
-                # token-derived ceiling without a single wasted step
-                if work >= max_iters:
-                    raise RuntimeError(
-                        f"engine made no progress after {max_iters} "
-                        f"iterations ({len(stats)}/{n} done)")
-                while ai < n and arrivals[ai].arrival <= it:
-                    sched.enqueue(arrivals[ai], wall=time.perf_counter(),
-                                  it=float(it))
-                    ai += 1
-                if not sched.has_work:
-                    # idle: fast-forward to the next arrival
-                    it = max(it + 1, int(np.ceil(arrivals[ai].arrival)))
-                    continue
+        def _prefill():
+            """One prefill chunk for the oldest prefilling request."""
+            nonlocal caches, prefill_chunks
+            pre = [s for s in sched.running
+                   if sched.slots[s].state == "prefill"]
+            if not pre:
+                return
+            s = min(pre, key=lambda s_: sched.slots[s_].seq)
+            st = sched.slots[s]
+            c = min(spec.prefill_chunk, len(st.req.prompt) - st.prefill_off)
+            with TraceAnnotation("engine.prefill", rid=st.req.rid, tokens=c):
+                if not sched.ensure_blocks(s, st.prefill_off + c - 1):
+                    return
+                tok = jnp.asarray(
+                    st.req.prompt[st.prefill_off:st.prefill_off + c],
+                    jnp.int32)[None, :]
+                logits, caches = paged_prefill_chunk(
+                    params, caches, tok, jnp.int32(st.prefill_off),
+                    jnp.asarray(sched.table[s:s + 1]),
+                    jnp.int32(c), jnp.int32(s), self.cfg,
+                    spec.block_len, spec.kv_qdtype)
+                prefill_chunks += 1
+                st.prefill_off += c
+                if st.prefill_off < len(st.req.prompt):
+                    return
+                st.state = "decode"
+                st.pos = len(st.req.prompt)
+                first = jnp.argmax(logits[0, c - 1])
+                with TraceAnnotation("engine.sync"):
+                    st.out.append(int(first))
+                if st.first_token_wall is None:
+                    st.first_token_wall = time.perf_counter()
+                if len(st.out) >= st.req.max_new_tokens:
+                    _retire(s)
 
-                for s in sched.admit_ready():
-                    caches = reset_slot_state(caches, s)
-
-                # one prefill chunk for the oldest prefilling request
-                pre = [s for s in sched.running
-                       if sched.slots[s].state == "prefill"]
-                if pre:
-                    s = min(pre, key=lambda s_: sched.slots[s_].seq)
-                    st = sched.slots[s]
-                    c = min(spec.prefill_chunk,
-                            len(st.req.prompt) - st.prefill_off)
-                    if sched.ensure_blocks(s, st.prefill_off + c - 1):
-                        tok = jnp.asarray(
-                            st.req.prompt[st.prefill_off:st.prefill_off + c],
-                            jnp.int32)[None, :]
-                        logits, caches = paged_prefill_chunk(
-                            params, caches, tok, jnp.int32(st.prefill_off),
-                            jnp.asarray(sched.table[s:s + 1]),
-                            jnp.int32(c), jnp.int32(s), self.cfg,
-                            spec.block_len, spec.kv_qdtype)
-                        prefill_chunks += 1
-                        st.prefill_off += c
-                        if st.prefill_off == len(st.req.prompt):
-                            st.state = "decode"
-                            st.pos = len(st.req.prompt)
-                            st.out.append(int(jnp.argmax(logits[0, c - 1])))
-                            if len(st.out) >= st.req.max_new_tokens:
-                                _retire(s)
-
-                # one batched decode step over every decode-state slot
-                dec = [s for s in sched.running
-                       if sched.slots[s].state == "decode"]
+        def _decode():
+            """One batched decode step over every decode-state slot."""
+            nonlocal caches, decode_calls
+            dec = [s for s in sched.running
+                   if sched.slots[s].state == "decode"]
+            if not dec:
+                return
+            with TraceAnnotation("engine.decode_feed"):
                 ready = []
                 for s in dec:
                     st = sched.slots[s]
@@ -250,28 +270,58 @@ class Engine:
                 # ...or a LATER one may have evicted an already-ready slot
                 ready = [s for s in ready if sched.slots[s] is not None
                          and sched.slots[s].state == "decode"]
-                if ready:
-                    feed = np.zeros((spec.slots, 1), np.int32)
-                    positions = np.zeros((spec.slots,), np.int32)
-                    active = np.zeros((spec.slots,), bool)
-                    for s in ready:
-                        st = sched.slots[s]
-                        feed[s, 0] = st.out[-1]
-                        positions[s] = st.pos
-                        active[s] = True
-                    logits, caches = paged_decode_step(
-                        params, caches, jnp.asarray(feed),
-                        jnp.asarray(positions), jnp.asarray(sched.table),
-                        jnp.asarray(active), self.cfg, spec.block_len,
-                        spec.kv_qdtype)
-                    decode_calls += 1
-                    nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-                    for s in ready:
-                        st = sched.slots[s]
-                        st.out.append(int(nxt[s]))
-                        st.pos += 1
-                        if len(st.out) >= st.req.max_new_tokens:
-                            _retire(s)
+                if not ready:
+                    return
+                feed = np.zeros((spec.slots, 1), np.int32)
+                positions = np.zeros((spec.slots,), np.int32)
+                active = np.zeros((spec.slots,), bool)
+                for s in ready:
+                    st = sched.slots[s]
+                    feed[s, 0] = st.out[-1]
+                    positions[s] = st.pos
+                    active[s] = True
+                feed, positions = jnp.asarray(feed), jnp.asarray(positions)
+                table, active = jnp.asarray(sched.table), jnp.asarray(active)
+            with TraceAnnotation("engine.dispatch", slots=len(ready)):
+                logits, caches = paged_decode_step(
+                    params, caches, feed, positions, table, active,
+                    self.cfg, spec.block_len, spec.kv_qdtype)
+                decode_calls += 1
+                nxt = jnp.argmax(logits[:, 0], axis=-1)
+            with TraceAnnotation("engine.sync"):
+                nxt = np.asarray(nxt)
+            for s in ready:
+                st = sched.slots[s]
+                st.out.append(int(nxt[s]))
+                st.pos += 1
+                if len(st.out) >= st.req.max_new_tokens:
+                    _retire(s)
+
+        with self.prepared.activate():
+            while len(stats) < n:
+                # guard on WORK iterations, not the simulated clock:
+                # idle fast-forwarding jumps `it` to absolute arrival
+                # timestamps, which a sparse trace can push past any
+                # token-derived ceiling without a single wasted step
+                if work >= max_iters:
+                    raise RuntimeError(
+                        f"engine made no progress after {max_iters} "
+                        f"iterations ({len(stats)}/{n} done)")
+                if not sched.has_work and arrivals[ai].arrival > it:
+                    # idle: fast-forward to the next arrival
+                    it = max(it + 1, int(np.ceil(arrivals[ai].arrival)))
+                    continue
+                with TraceAnnotation("engine.iter", it=it):
+                    with TraceAnnotation("engine.admit"):
+                        while ai < n and arrivals[ai].arrival <= it:
+                            sched.enqueue(arrivals[ai],
+                                          wall=time.perf_counter(),
+                                          it=float(it))
+                            ai += 1
+                        for s in sched.admit_ready(wall=time.perf_counter()):
+                            caches = reset_slot_state(caches, s)
+                    _prefill()
+                    _decode()
                 it += 1
                 work += 1
 
@@ -281,6 +331,7 @@ class Engine:
             wall_s=time.perf_counter() - t0,
             model_calls=prefill_chunks + decode_calls,
             prefill_chunks=prefill_chunks, decode_calls=decode_calls,
+            iterations=work,
             evictions=sched.evictions,
             max_blocks_in_use=sched.max_blocks_in_use,
             num_blocks=self.num_blocks)

@@ -65,6 +65,10 @@ class SlotState:
     out: List[int] = dataclasses.field(default_factory=list)
     enqueue_wall: float = 0.0
     enqueue_iter: float = 0.0
+    # first admission and first token: kept across preemption, since
+    # the user already saw that token
+    admit_wall: Optional[float] = None
+    first_token_wall: Optional[float] = None
 
 
 class PagedScheduler:
@@ -136,11 +140,12 @@ class PagedScheduler:
             return self.waiting
         return None
 
-    def admit_ready(self) -> List[int]:
+    def admit_ready(self, *, wall: float = 0.0) -> List[int]:
         """Fill free slots from the queues (FIFO, no head-of-line bypass
         — determinism under a fixed seed is part of the test contract).
         Returns newly admitted slot indices (their per-slot recurrent
-        state must be reset by the engine)."""
+        state must be reset by the engine).  ``wall`` stamps a request's
+        first admission."""
         admitted = []
         for s in range(self.nslots):
             if self.slots[s] is not None:
@@ -158,6 +163,8 @@ class PagedScheduler:
             st.prefill_off = 0
             st.pos = 0
             st.out = []
+            if st.admit_wall is None:
+                st.admit_wall = wall
             self.slots[s] = st
             self.table[s, :] = 0
             self.owned[s] = []

@@ -15,6 +15,7 @@ import this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,3 +154,28 @@ def test_flash_attention_compiles(one_chip):
         lambda a, k, v: dispatch.attention(a, k, v, causal=True, chunk=64),
         qg, kv, kv)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("layout,n,qdtype,entry", [
+    ("dense", 4, None, "tile_gemm"),
+    ("compressed", 2, None, "nm_spmm"),
+    ("compressed", 2, "int8", "nm_spmm_int8"),
+])
+def test_kernels_are_named_in_the_hlo(one_chip, layout, n, qdtype, entry):
+    """A trace names each Pallas call by its HLO text: the custom call
+    takes the kernel's name, and its ``kernel_metadata`` the registry
+    entry that runs it, single and fused gate-up alike."""
+    meta = re.compile(r'kernel_metadata=\{\s*"kernel":"(\w+)"\s*\}')
+    cfg, p = _linear(one_chip, D_MODEL, D_MODEL, layout, n, qdtype)
+    text = _compile(lambda x, pp: dispatch.sparse_matmul(x, pp, cfg),
+                    _acts(one_chip, DECODE_B, D_MODEL), p)
+    family = entry.removesuffix("_int8")
+    assert re.search(rf"%{family}\.\d+ = \S+ custom-call\(", text), entry
+    assert meta.findall(text) == [entry]
+    cfg, pg = _linear(one_chip, D_MODEL, D_FF, layout, n, qdtype)
+    _, pu = _linear(one_chip, D_MODEL, D_FF, layout, n, qdtype)
+    text = _compile(
+        lambda x, g, u: dispatch.gate_up_matmul(x, g, u, cfg),
+        _acts(one_chip, DECODE_B, D_MODEL), pg, pu)
+    assert re.search(rf"%{family}_dual\.\d+ = \S+ custom-call\(", text)
+    assert meta.findall(text) == [entry]
