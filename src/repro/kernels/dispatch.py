@@ -284,6 +284,7 @@ class DispatchDecision:
     reason_code: Optional[ReasonCode] = None       # catalog identity of ``reason``
     epilogue_reason: Optional[ReasonCode] = None   # fused, or why not
     activation_reason: Optional[ReasonCode] = None  # skip, or why mask-only
+    mux: Optional[str] = None      # nm_spmm family's M:1 mux: slab | rows
 
     @property
     def uses_kernel(self) -> bool:
@@ -323,6 +324,8 @@ def describe(d: DispatchDecision) -> str:
             f"blocks=(b={bb},ke={bke},o={bo})")
     if d.dtype is not None:
         base += f" dtype={d.dtype}"
+    if d.mux is not None:
+        base += f" mux={d.mux}"
     if d.epilogue is not None:
         base += f" epilogue={d.epilogue}[{_epi_annotation(d)}]"
     if d.activation is not None:
@@ -1270,6 +1273,11 @@ def plan(
         else:
             act_code = ReasonCode.ACT_SKIP
     skip = act_code is ReasonCode.ACT_SKIP
+    # the nm_spmm family's M:1 mux follows n (slab when n divides 4)
+    mux = None
+    if p.mode == "compressed":
+        from repro.kernels.nm_spmm.kernel import mux_form
+        mux = mux_form(p.n)
 
     def _decision(blocks, code, source):
         return DispatchDecision(
@@ -1280,7 +1288,7 @@ def plan(
             epilogue=p.epilogue, epilogue_fused=fused,
             activation=p.activation, activation_skip=skip,
             reason_code=code, epilogue_reason=epi_code,
-            activation_reason=act_code)
+            activation_reason=act_code, mux=mux)
 
     if dcfg.blocks is not None:
         return _decision(tuple(dcfg.blocks), ReasonCode.BLOCKS_PINNED,
@@ -1463,10 +1471,12 @@ def dispatch_report(params_tree, batches, cfg,
     will actually run (e.g. ``(slots, prefill_chunk)`` — decode steps
     and prefill chunks can plan differently, and the report shows both).
     Shard-aware: under a mesh env each line carries global -> local
-    shapes and the chosen collective.  Ends with the autotune cache
-    counters.  This is the engine-owned successor of the plan report
-    ``launch/serve.py`` used to build privately; the launcher, the
-    examples, and ``Prepared.dispatch_report`` all render these lines.
+    shapes and the chosen collective.  Ends with the count of
+    ``nm_spmm`` plan lines by mux form (``mux=slab`` / ``mux=rows``) and
+    the autotune cache counters.  This is the engine-owned successor of
+    the plan report ``launch/serve.py`` used to build privately; the
+    launcher, the examples, and ``Prepared.dispatch_report`` all render
+    these lines.
     """
     from repro.core.sparse_linear import gather_hint
     from . import autotune as kautotune
@@ -1538,6 +1548,12 @@ def dispatch_report(params_tree, batches, cfg,
                 str(kv[0][5]))):
         lines.append(f"  [gate-up {hint or 'rep'}] {n}:{cfg.m} "
                      f"global (B={batch}, K={ke}, O={o}) {describe(d)}")
+    # how often the slab mux engages: nm_spmm plan lines by mux form
+    forms = [d.mux for d in (*seen.values(), *dual_seen.values())
+             if d.uses_kernel and d.mux is not None]
+    if forms:
+        lines.append(f"  nm_spmm mux: {forms.count('slab')} slab / "
+                     f"{forms.count('rows')} rows site(s)")
     st = kautotune.stats()
     lines.append(f"  autotune cache: {st['hits']} hit(s) / "
                  f"{st['misses']} miss(es)")

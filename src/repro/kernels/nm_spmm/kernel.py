@@ -9,20 +9,34 @@ TPU mapping of the paper's SPE input-mux (DESIGN.md §2, Tier 1):
   * the dense weight tile **never exists in HBM** — HBM traffic for the
     sparse operand is N/M of dense (+ 2-bit metadata);
   * the M:1 mux becomes a VPU one-hot select producing the expanded
-    ``(BK_eff, BO)`` tile in VMEM, ~N compare+select ops per expanded
-    element, amortized over the MXU's BB-deep matmul;
+    ``(BK_eff, BO)`` tile in VMEM, amortized over the MXU's BB-deep
+    matmul;
   * the fp32 accumulator tile lives in VMEM across the K grid — the
     "output forwarding" equivalent (no C round-trip between accumulating
     instructions).
 
-Only reshapes that preserve the trailing (lane) dimension are used, so the
-body lowers on Mosaic as well as in interpret mode.
+Two forms of the mux, chosen by ``n`` (:func:`mux_form`):
+
+``slab`` (``n`` divides 4).  Meta byte ``i`` holds the indices of
+compressed rows ``4i..4i+3``, and those rows feed whole blocks: the
+dense rows ``P*i .. P*i+P-1`` with ``P = 16/n``.  So the field
+``(pm >> 2q) & 3`` and a sublane-strided load of the values' 32-bit view
+give aligned ``(BK_c/4, BO)`` slabs, one per field, and every dense row
+is a lane-local select over 32-bit words: no row ever moves between
+sublanes.  The expanded tile comes out with K permuted inside each
+tile; the wrapper permutes the activation to the same order
+(:func:`slab_order`, plain jnp ahead of the ``pallas_call``).
+
+``rows`` (any other ``n``).  Meta and values are repeated 4x along the
+rows and selected against a position pattern: sublane relayouts, kept
+for the ``n`` the slabs cannot take.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -32,6 +46,93 @@ from repro.kernels.epilogue import (
 from repro.kernels.registry import kernel_label
 
 _IDENT = EpilogueSpec()
+_WORD_BITS = 32
+
+
+def mux_form(n: int) -> str:
+    """``"slab"`` when ``n`` divides M=4, else ``"rows"``."""
+    return "slab" if 4 % n == 0 else "rows"
+
+
+def _word_fields(dtype) -> int:
+    """Values per 32-bit word: 1 (f32), 2 (bf16), 4 (int8, fp8)."""
+    return _WORD_BITS // (jnp.dtype(dtype).itemsize * 8)
+
+
+def slab_order(x: jax.Array, n: int, block_ke: int, dtype) -> jax.Array:
+    """Permute each ``block_ke`` K-tile of ``x (B, K_eff)`` into the row
+    order of the slab mux's expanded tile for ``dtype`` values.
+
+    Dense row ``P*i + F*v + t`` of a tile (``P = 16/n`` dense rows per
+    meta row, ``F`` values per 32-bit word) is row ``F*(v*S + i) + t``
+    of the expanded tile, ``S = block_ke/P``.  Identity for ``rows``.
+    """
+    if mux_form(n) != "slab":
+        return x
+    b, ke = x.shape
+    f = _word_fields(dtype)
+    p = 16 // n
+    s = block_ke // p
+    return (x.reshape(b, ke // block_ke, s, p // f, f)
+            .swapaxes(2, 3).reshape(b, ke))
+
+
+def _moved(word: jax.Array, t: int, t2: int, bits: int, f: int) -> jax.Array:
+    """Field ``t`` of each 32-bit ``word`` moved to field ``t2``, the
+    other fields cleared (a mask only where a shift leaves one)."""
+    d = bits * (t2 - t)
+    if d > 0:
+        word = lax.shift_left(word, jnp.uint32(d))
+    elif d < 0:
+        word = lax.shift_right_logical(word, jnp.uint32(-d))
+    if any(0 <= u + t2 - t < f for u in range(f) if u != t):
+        word = lax.bitwise_and(word,
+                               jnp.uint32(((1 << bits) - 1) << (bits * t2)))
+    return word
+
+
+def _slab_mux(v_ref, pm_ref, n: int) -> jax.Array:
+    """The relayout-free M:1 mux: ``(BK_c, BO)`` values + ``(BK_c/4, BO)``
+    packed meta -> the ``(BK_c*4/n, BO)`` expanded tile, rows in
+    :func:`slab_order`.
+
+    Compressed row ``4i+q`` is field ``q % F`` of word ``4i/F + q//F`` of
+    the values' 32-bit view; a stride-``4/F`` load gathers one word per
+    meta row.  Output word ``v`` of meta row ``i`` packs dense positions
+    ``F*v .. F*v+F-1``; position ``4g+j`` takes the field ``q`` of block
+    ``g`` (``q = g*n .. g*n+n-1``) whose index is ``j``, else 0.  Indices
+    are unique within a block, so at most one field matches and the
+    select chain is exact for every dtype.  All the work is 32-bit
+    integer compares, selects, shifts and ORs, written as ``lax``
+    primitives: the body is traced once per ``pallas_call``, and each
+    program of a served model traces several.
+    """
+    bkc, bo = v_ref.shape
+    f = _word_fields(v_ref.dtype)
+    bits = _WORD_BITS // f
+    s = bkc // 4
+    pm = lax.convert_element_type(pm_ref[...], jnp.int32)
+    # idx_q == j  <=>  (pm & 3 << 2q) == j << 2q: no shift per field
+    idx = [lax.bitwise_and(pm, 3 << (2 * q)) for q in range(4)]
+    w_ref = v_ref.bitcast(jnp.uint32)
+    words = [w_ref[pl.ds(u, s, stride=4 // f), :] for u in range(4 // f)]
+    zero = lax.full((s, bo), 0, jnp.uint32)
+    moved = {}
+    out = []
+    for v in range(16 // n // f):
+        word = None
+        for t2 in range(f):
+            g, j = divmod(f * v + t2, 4)
+            sel = zero
+            for q in range(g * n, (g + 1) * n):
+                if (q, t2) not in moved:
+                    u, t = divmod(q, f)
+                    moved[q, t2] = _moved(words[u], t, t2, bits, f)
+                sel = lax.select(lax.eq(idx[q], j << (2 * q)),
+                                 moved[q, t2], sel)
+            word = sel if word is None else lax.bitwise_or(word, sel)
+        out.append(word)
+    return pltpu.bitcast(lax.concatenate(out, 0), v_ref.dtype)
 
 
 def _expand_rows4(a: jax.Array) -> jax.Array:
@@ -72,6 +173,14 @@ def _decompress_tile(v: jax.Array, idx: jax.Array, n: int) -> jax.Array:
     return out.astype(v.dtype)
 
 
+def _mux_tile(v_ref, pm_ref, n: int) -> jax.Array:
+    """The expanded ``(BK_c*4/n, BO)`` weight tile, through the mux form
+    that ``n`` selects (:func:`mux_form`)."""
+    if mux_form(n) == "slab":
+        return _slab_mux(v_ref, pm_ref, n)
+    return _decompress_tile(v_ref[...], _unpack_meta_tile(pm_ref[...]), n)
+
+
 def _spmm_accumulate(x_ref, v_ref, pm_ref, acc_ref, n: int, acc_dtype):
     """The shared mux-expand + contract step: init the accumulator tile on
     the first K step, decompress the values tile through the in-VMEM M:1
@@ -81,8 +190,7 @@ def _spmm_accumulate(x_ref, v_ref, pm_ref, acc_ref, n: int, acc_dtype):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    idx = _unpack_meta_tile(pm_ref[...])
-    w = _decompress_tile(v_ref[...], idx, n)
+    w = _mux_tile(v_ref, pm_ref, n)
     acc_ref[...] += jnp.dot(x_ref[...], w, preferred_element_type=acc_dtype)
 
 
@@ -156,6 +264,7 @@ def nm_spmm(
     block_kc = block_ke * n // 4
     assert block_kc % 4 == 0, "block_ke*n/4 must be a multiple of 4 for packing"
     nk = ke // block_ke
+    x = slab_order(x, n, block_ke, values.dtype)
     return pl.pallas_call(
         lambda *refs: _spmm_kernel(*refs, n=n, nk=nk, acc_dtype=jnp.float32,
                                    quant=False, epi=epi),
@@ -216,6 +325,7 @@ def _nm_spmm_quantized(
     block_kc = block_ke * n // 4
     assert block_kc % 4 == 0, "block_ke*n/4 must be a multiple of 4 for packing"
     nk = ke // block_ke
+    x_q = slab_order(x_q, n, block_ke, values.dtype)
     if raw:
         return pl.pallas_call(
             lambda xr, vr, pr, orf, acc: _spmm_q_raw_kernel(
@@ -291,8 +401,7 @@ def _spmm_masked_kernel(*refs, n: int, nk: int, acc_dtype, quant: bool,
 
     @pl.when(kmask_ref[i, kk] != 0)
     def _accumulate():
-        idx = _unpack_meta_tile(pm_ref[...])
-        w = _decompress_tile(v_ref[...], idx, n)
+        w = _mux_tile(v_ref, pm_ref, n)
         acc_ref[...] += jnp.dot(x_ref[...], w,
                                 preferred_element_type=acc_dtype)
 
@@ -355,6 +464,7 @@ def nm_spmm_masked(
     block_kc = block_ke * n // 4
     assert block_kc % 4 == 0, "block_ke*n/4 must be a multiple of 4 for packing"
     nk = ke // block_ke
+    x = slab_order(x, n, block_ke, values.dtype)
     assert kmap.shape == (b // block_b, nk) == kmask.shape, (
         kmap.shape, kmask.shape, (b // block_b, nk))
 
@@ -421,8 +531,8 @@ def _spmm_dual_kernel(*refs, n: int, nk: int, acc_dtype, quant: bool,
         accu_ref[...] = jnp.zeros_like(accu_ref)
 
     xv = x_ref[...]  # ONE read feeds both mux-expanded contractions
-    wg = _decompress_tile(vg_ref[...], _unpack_meta_tile(pmg_ref[...]), n)
-    wu = _decompress_tile(vu_ref[...], _unpack_meta_tile(pmu_ref[...]), n)
+    wg = _mux_tile(vg_ref, pmg_ref, n)
+    wu = _mux_tile(vu_ref, pmu_ref, n)
     accg_ref[...] += jnp.dot(xv, wg, preferred_element_type=acc_dtype)
     accu_ref[...] += jnp.dot(xv, wu, preferred_element_type=acc_dtype)
 
@@ -477,6 +587,7 @@ def nm_spmm_dual(
     block_kc = block_ke * n // 4
     assert block_kc % 4 == 0, "block_ke*n/4 must be a multiple of 4 for packing"
     nk = ke // block_ke
+    x = slab_order(x, n, block_ke, values_g.dtype)
     x_spec = pl.BlockSpec((block_b, block_ke), lambda i, j, kk: (i, kk))
     v_spec = pl.BlockSpec((block_kc, block_o), lambda i, j, kk: (kk, j))
     pm_spec = pl.BlockSpec((block_kc // 4, block_o), lambda i, j, kk: (kk, j))

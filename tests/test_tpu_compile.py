@@ -76,6 +76,20 @@ def _compile(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_texts(fn, *args):
+    """Each Pallas call of the compiled program as a profiler trace names
+    it: its HLO instruction with the operand shapes inline (which
+    ``as_text`` leaves out)."""
+    from jax._src.lib import xla_client as xc
+
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    exe = jax.jit(fn).lower(*args).compile().runtime_executable()
+    return [ln.strip().removeprefix("ROOT ")
+            for m in exe.hlo_modules() for ln in m.to_string(opts).splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
 @pytest.mark.parametrize("layout,n,qdtype,kernel", [
     ("dense", 4, None, "tile_gemm"),
     ("dense", 4, "int8", "tile_gemm_int8"),
@@ -179,3 +193,38 @@ def test_kernels_are_named_in_the_hlo(one_chip, layout, n, qdtype, entry):
         _acts(one_chip, DECODE_B, D_MODEL), pg, pu)
     assert re.search(rf"%{family}_dual\.\d+ = \S+ custom-call\(", text)
     assert meta.findall(text) == [entry]
+
+
+@pytest.mark.parametrize("n", [2, 1])
+def test_nm_spmm_sites_read_as_the_benchmark_reads_them(one_chip, n):
+    """Every ``nm_spmm`` site (single and fused gate-up, decode and
+    prefill-chunk batch) compiles to ONE Pallas call — the slab mux's
+    activation permutation is plain XLA, not a second kernel — that
+    exactly ``bench/kernels/linear.json`` claims, and whose operands
+    ``bench.trace.linear_counts`` reads as the activation, ``(K_c, O)``
+    values and ``(K_c/4, O)`` packed meta."""
+    from bench import trace
+    from bench.harness import HERE
+
+    classes = trace.load_classes(HERE / "kernels")
+    for b in (DECODE_B, PREFILL_B):
+        calls = []
+        for site, (k, o) in SITES.items():
+            cfg, p = _linear(one_chip, k, o, "compressed", n, None)
+            calls.append((site, k, o, 1, _kernel_texts(
+                lambda x, pp: dispatch.sparse_matmul(x, pp, cfg),
+                _acts(one_chip, b, k), p)))
+        cfg, pg = _linear(one_chip, D_MODEL, D_FF, "compressed", n, None)
+        _, pu = _linear(one_chip, D_MODEL, D_FF, "compressed", n, None)
+        calls.append(("gate_up dual", D_MODEL, D_FF, 2, _kernel_texts(
+            lambda x, g, u: dispatch.gate_up_matmul(x, g, u, cfg),
+            _acts(one_chip, b, D_MODEL), pg, pu)))
+        for site, k, o, weights, texts in calls:
+            assert len(texts) == 1, (site, b, texts)
+            cls = trace.classify(trace.Op(texts[0], 0, 1), classes)
+            assert cls is not None and cls["class"] == "linear", (site, b)
+            kc = k * n // 4
+            assert trace.linear_counts(texts[0]) == (
+                2 * b * weights * kc * o,
+                b * k * 2 + weights * (kc * o * 2 + kc // 4 * o)
+                + b * o * 2), (site, b, texts[0][:300])
