@@ -45,38 +45,58 @@ def model_config(cfg: dict):
 
 
 def _leaf(w, layout: str, sparsity):
-    """One stacked (layers, in, out) linear in the program's layout, with
-    the program's (layers, 1, ...) stacking."""
+    """One layer's (in, out) linear in the program's layout, with the
+    program's leading repeat axis of 1."""
     from repro.core import nm
 
     if layout == "dense":
-        return {"w": w[:, None]}
+        return {"w": w[None]}
     if layout == "compressed":
         n, m = sparsity
-        c = jax.vmap(lambda x: nm.compress_nm(x, n, m))(w)
-        return {"values": c.values[:, None],
-                "meta_packed": jax.vmap(nm.pack_meta)(c.meta)[:, None]}
+        c = nm.compress_nm(w, n, m)
+        return {"values": c.values[None],
+                "meta_packed": nm.pack_meta(c.meta)[None]}
     raise ValueError(f"no tree adapter for layout {layout!r}")
 
 
-def program_params(ref, seed: int, cfg: dict):
-    """The program's parameter tree, made on the device in one call."""
+def build(ref, cfg: dict, shardings=None):
+    """The jitted build of the program's tree from a key.
+
+    The embedding and the head are made whole; the layers one at a time
+    under ``lax.map``: each layer's dense bfloat16 weights are made by
+    ``ref.layer_weights``, pruned and compressed, and written into the
+    stacked leaves before the next layer's are made, so the build holds
+    one layer's dense weights beside the tree.  ``shardings`` (a tree of
+    shardings shaped as the result, or ``None``) places every leaf as it
+    is built; XLA carries each leaf's placement into the loop that
+    writes it.  (A constraint on each layer inside the loop made a
+    v5e:2x2 compile hold more, not less.)"""
     serve = cfg["program"]
     sparsity = serve.get("sparsity")
     sp = None if sparsity is None else tuple(sparsity)
     layers, d = cfg["num_hidden_layers"], cfg["hidden_size"]
 
-    def build(key):
+    def fn(key):
         emb, head = ref.embedding_weights(key, cfg)
-        ws = jax.vmap(lambda i: ref.layer_weights(key, i, cfg, sp))(
-            jnp.arange(layers))
-        slot = {"norm1": {"gamma": jnp.zeros((layers, 1, d), jnp.float32)},
-                "norm2": {"gamma": jnp.zeros((layers, 1, d), jnp.float32)},
-                "mixer": {}, "ffn": {}}
-        for name, (group, pname) in PROGRAM_NAMES.items():
-            slot[group][pname] = _leaf(ws[name], serve["layout"], sp)
+
+        def one_layer(i):
+            ws = ref.layer_weights(key, i, cfg, sp)
+            out = {"mixer": {}, "ffn": {}}
+            for name, (group, pname) in PROGRAM_NAMES.items():
+                out[group][pname] = _leaf(ws[name], serve["layout"], sp)
+            return out
+
+        slot = jax.lax.map(one_layer, jnp.arange(layers))
+        slot["norm1"] = {"gamma": jnp.zeros((layers, 1, d), jnp.float32)}
+        slot["norm2"] = {"gamma": jnp.zeros((layers, 1, d), jnp.float32)}
         return {"embed": emb, "unembed": head,
                 "final_norm": {"gamma": jnp.zeros((d,), jnp.float32)},
                 "stages": [{"slot0": slot}]}
 
-    return jax.jit(build)(ref.seed_key(seed))
+    return jax.jit(fn, out_shardings=shardings)
+
+
+def program_params(ref, seed: int, cfg: dict, shardings=None):
+    """The program's parameter tree, made on the device in one call
+    (:func:`build`), placed by ``shardings`` where given."""
+    return build(ref, cfg, shardings)(ref.seed_key(seed))

@@ -89,17 +89,37 @@ def _read_json(path: Path) -> dict:
     return json.loads(path.read_text())
 
 
+def _check_mesh(workload: str, chips: int, config: dict) -> None:
+    """A cell on more than one chip runs over its configuration's mesh
+    (``program.mesh``, ``[data, model]``), which spans exactly its chips."""
+    mesh = config["program"].get("mesh")
+    if mesh is None:
+        if chips > 1:
+            raise BenchError(f"{workload}: {chips} chips, and its "
+                             f"configuration states no program.mesh")
+        return
+    if (len(mesh) != 2 or not all(isinstance(a, int) and a > 0
+                                  for a in mesh)):
+        raise BenchError(f"{workload}: program.mesh {mesh!r} is not "
+                         f"[data, model] of positive whole numbers")
+    if mesh[0] * mesh[1] != chips:
+        raise BenchError(f"{workload}: program.mesh {mesh} spans "
+                         f"{mesh[0] * mesh[1]} chips, the cell asks for "
+                         f"{chips}")
+
+
 def load_cell(root: Path, workload: str) -> Cell:
     """The cell ``workload`` as the files under ``root`` describe it."""
     bench = _read_json(root / "BENCHMARK.json")
     w = _by_name(bench["workloads"], workload, "workload")
     c = _by_name(bench["configs"], w["config"], "config")
+    chips, config = int(w["chips"]), _read_json(root / c["file"])
+    _check_mesh(workload, chips, config)
 
     def applies(m):
         return "workloads" not in m or workload in m["workloads"]
 
-    return Cell(name=workload, chips=int(w["chips"]),
-                config=_read_json(root / c["file"]),
+    return Cell(name=workload, chips=chips, config=config,
                 mix=_read_json(root / "bench" / "traffic"
                                / f"{w['traffic']}.json"),
                 limits=_read_json(root / "bench" / "limits"
@@ -123,7 +143,7 @@ class Observed:
     traced_report: Any = None      # ServingReport of the traced segment
 
     def peak(self) -> dict:
-        """The chip's published peaks; an unknown kind fails the run."""
+        """One chip's published peaks; an unknown kind fails the run."""
         return counting.peaks(self.device_kind)
 
 
@@ -178,12 +198,12 @@ def device_info() -> dict:
             "count": len(devs)}
 
 
-def memory_peak_bytes() -> int:
+def memory_peaks() -> List[int]:
+    """``peak_bytes_in_use`` of each device, in ``jax.devices()`` order."""
     import jax
 
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in jax.devices()]
-    return int(max(peaks))
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in jax.devices()]
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
@@ -241,13 +261,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             traced = reduce_trace(Path(tdir.name), traced_s,
                                   HERE / "kernels", peak)
     dev = device_info()
-    dev["memory_peak_bytes"] = memory_peak_bytes()
+    peaks = memory_peaks()
+    dev["memory_peak_bytes"] = max(peaks)
     attempted = len(segments) * mix["segment_requests"]
     log(f"window: {len(segments)} segment(s) of {mix['segment_requests']} "
         f"requests, {len(done)}/{attempted} finished, "
         f"{sum(r.generated_tokens for r in segments)} tokens in "
         f"{window_s:.3f}s (segments {[round(w, 2) for w in walls]} s); "
-        f"compilations in the window: {counter.count}")
+        f"compilations in the window: {counter.count}; peak bytes per "
+        f"device {peaks}")
     obs = Observed(cell=cell, slots=served.spec.slots, setup_s=setup_s,
                    window_s=window_s, segments=segments, done=done,
                    device_kind=dev["kind"], trace=traced,

@@ -1,7 +1,7 @@
 """Model FLOPs of the traced segment's finished requests over (its
-seconds x the chip's bf16 peak).  The FLOPs are the configuration's
-mathematics: nonzero weights, attention over live positions, and the
-output head where a token is sampled."""
+seconds x the cell's chips x one chip's bf16 peak).  The FLOPs are the
+configuration's mathematics: nonzero weights, attention over live
+positions, and the output head where a token is sampled."""
 
 from bench import counting
 
@@ -16,4 +16,5 @@ def read(obs):
         [(s.prompt_len, s.new_tokens) for s in r.stats],
         ref.linear_shapes(cfg), z["layers"], z["heads"], z["head_dim"],
         z["d"], z["vocab"], obs.cell.sparsity)
-    return 100.0 * flops / (obs.trace.window_s * peak["bf16_flops_per_s"])
+    return 100.0 * flops / (obs.trace.window_s * obs.cell.chips
+                            * peak["bf16_flops_per_s"])

@@ -10,7 +10,10 @@ nanoseconds.  The host plane holds what the host threads were doing,
 Python frames on the line named after the interpreter (``python3``).  From these:
 
 - busy time: the union of the operation intervals, averaged over the
-  device planes that ran anything;
+  device planes that ran anything, as are linear time and each
+  operation kind's time: every number is one chip's;
+- idle gaps: the gaps in the union of every plane's operations, when
+  no chip ran anything;
 - program time: each execution of a jitted program, by its name;
 - kernel classes: every Pallas kernel (an operation whose text names
   ``custom_call_target="tpu_custom_call"``) is matched against the
@@ -201,19 +204,18 @@ def classify(op: Op, classes: List[dict]) -> Optional[dict]:
 
 def reduce_ops(devices, host, window_s: float, classes: List[dict],
                peak: Optional[dict]) -> Reduced:
-    t_busy, n_busy = 0.0, 0
+    """Per chip: busy time, linear time and least time, and each
+    operation kind's time are means over the device planes that ran
+    anything; idle gaps are those in which none of them ran."""
+    planes = [(ops, mods) for _, ops, mods in devices if ops]
+    n = max(len(planes), 1)
+    t_busy = 0.0
     programs: Dict[str, List[float]] = {}
     per_op: Dict[str, float] = {}
     linear_calls, linear_s, least = 0, 0.0, 0.0
     least_known = peak is not None
-    all_gaps = []
-    for _, ops, mods in devices:
-        if not ops:
-            continue
-        b, gaps = busy_union(ops)
-        t_busy += b
-        n_busy += 1
-        all_gaps += gaps
+    for ops, mods in planes:
+        t_busy += busy_union(ops)[0]
         for m in mods:
             programs.setdefault(_program(m.name), []).append(m.dur_ns * 1e-9)
         for o in ops:
@@ -227,14 +229,15 @@ def reduce_ops(devices, host, window_s: float, classes: List[dict],
                 f, by = _linear_counts_cached(o.name)
                 if least_known:
                     least += least_time(f, by, peak)
-    busy = t_busy / n_busy if n_busy else 0.0
-    top = sorted(per_op.items(), key=lambda kv: -kv[1])
-    return Reduced(window_s=window_s, busy_s=busy, programs=programs,
-                   linear_calls=linear_calls, linear_s=linear_s,
-                   linear_least_s=least if least_known and linear_calls
+    top = sorted(((k, v / n) for k, v in per_op.items()),
+                 key=lambda kv: -kv[1])
+    gaps = busy_union([o for ops, _ in planes for o in ops])[1]
+    return Reduced(window_s=window_s, busy_s=t_busy / n, programs=programs,
+                   linear_calls=linear_calls, linear_s=linear_s / n,
+                   linear_least_s=least / n if least_known and linear_calls
                    else None,
                    top_ops=top,
-                   idle_gaps=_label_gaps(all_gaps, host))
+                   idle_gaps=_label_gaps(gaps, host))
 
 
 _COUNTS: Dict[str, Tuple[float, float]] = {}

@@ -13,6 +13,7 @@ import pytest
 
 from bench import arrivals, counting, sut
 from bench.harness import HERE, _load_module
+from test_bench_cells import MEDIUM
 
 ROOT = Path(__file__).resolve().parents[2]
 ref = _load_module(HERE / "reference" / "internlm2.py", "bench_ref_test")
@@ -143,6 +144,37 @@ def test_program_tree_holds_reference_weights():
                                   np.asarray(w["w_down"], np.float32))
     kept = np.asarray(w["w_down"] != 0).reshape(-1, 4, w["w_down"].shape[1])
     assert (kept.sum(axis=1) == 2).all()
+
+
+def temp_bytes(jitted, key):
+    return jitted.lower(key).compile().memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("layout,sparsity", [("dense", None),
+                                             ("compressed", (2, 4))])
+def test_build_holds_one_layers_dense_weights(layout, sparsity):
+    """The compiled build at the MEDIUM size keeps at most one layer's
+    dense weights alive beside the tree: six more layers add less than
+    one layer's dense weights to its temporaries, and they are at most
+    one layer's dense weights, the embedding and head, and the scratch
+    that making the embedding and head, or one layer, takes alone (the
+    CPU's threefry works in buffers several times its output; compiled
+    for a v5e, the 2:4 build's temporaries are under the first two
+    alone)."""
+    cfg = dict(smoke_config(layout, sparsity), **MEDIUM)
+    key = ref.seed_key(3)
+    dense_layer = sum(2 * k * o for k, o in ref.linear_shapes(cfg).values())
+    emb_head = 2 * 2 * cfg["vocab_size"] * cfg["hidden_size"]
+    making = max(
+        temp_bytes(jax.jit(lambda k: ref.embedding_weights(k, cfg)), key),
+        temp_bytes(jax.jit(lambda k: ref.layer_weights(k, 0, cfg, sparsity)),
+                   key))
+    deep = temp_bytes(adapter.build(ref, cfg), key)
+    shallow = temp_bytes(adapter.build(ref, dict(cfg, num_hidden_layers=2)),
+                         key)
+    assert cfg["num_hidden_layers"] == 8
+    assert deep - shallow < dense_layer
+    assert deep <= dense_layer + emb_head + making
 
 
 def test_program_tree_matches_init_params_structure():

@@ -5,14 +5,17 @@ made by ``bench/record_trace_fixture.py``: one 32-token prompt through
 internlm2-1.8B 2:4 served with 16 slots of 256 positions and 128-token
 prefill chunks, one prefill chunk and two decode steps)."""
 
+import dataclasses
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
 from bench import counting, trace
-from bench.harness import HERE
+from bench.harness import HERE, Observed, _load_module, load_cell
 
+ROOT = Path(__file__).resolve().parents[2]
 FIXTURE = Path(__file__).resolve().parent / "fixtures"
 KERNELS = HERE / "kernels"
 PEAK = counting.peaks("TPU v5 lite")
@@ -162,3 +165,85 @@ def test_recorded_trace_reduction(recorded):
     assert red.breakdown()["device_ops"][0][0] == "linear"
     assert all(n.split(": ")[0] in {line for line, *_ in host}
                for n, _ in red.idle_gaps)
+
+
+# ------------------------------------------------------- per chip
+PER_CHIP = ("linear_share_pct", "linear_roofline", "device_idle_pct",
+            "decode_step_ms")
+
+
+def reader(name):
+    return _load_module(HERE / "metrics" / f"{name}.py", f"reader_{name}")
+
+
+def readings(red):
+    obs = SimpleNamespace(trace=red)
+    return {m: reader(m).read(obs) for m in PER_CHIP}
+
+
+def test_four_planes_read_as_one():
+    """The same operations on four device planes, as a (1, 4) mesh runs
+    them: every per-layer reading and every second of the breakdown is
+    one chip's, as on one plane."""
+    ops = [op(WHILE, 0, 4000), op(LINEAR, 0, 1000), op(FUSION, 1000, 500),
+           op(LINEAR, 3000, 1000), op(FUSION, 9000, 700),
+           op(LINEAR, 12000, 3000)]
+    mods = [op("jit_paged_decode_step(7)", 0, 4000),
+            op("jit_paged_decode_step(7)", 12000, 3000)]
+    host = [("python", "$array.py:631 _value", 4100, 4800),
+            ("python", "engine.iter", 9800, 2100)]
+    classes = trace.load_classes(KERNELS)
+
+    def reduce(n):
+        planes = [(f"/device:TPU:{i}", ops, mods) for i in range(n)]
+        return trace.reduce_ops(planes, host, 2e-5, classes, PEAK)
+
+    one, four = reduce(1), reduce(4)
+    assert readings(four) == pytest.approx(readings(one), rel=1e-12)
+    assert 0 < readings(four)["linear_share_pct"] <= 100
+    b1, b4 = one.breakdown(), four.breakdown()
+    for part in ("device_ops", "idle_gaps"):
+        assert [n for n, _ in b4[part]] == [n for n, _ in b1[part]]
+        assert [s for _, s in b4[part]] == pytest.approx(
+            [s for _, s in b1[part]], rel=1e-12)
+    assert b1["idle_gaps"] == [["python: $array.py:631 _value",
+                                pytest.approx(5e-6)],
+                               ["python: engine.iter",
+                                pytest.approx(2.3e-6)]]
+
+
+def test_mfu_is_per_chip():
+    """The same finished requests in the same window read a quarter on
+    four chips of what they read on one."""
+    cell = load_cell(ROOT, "internlm2-1_8b-2of4.chat_short")
+    red = trace.reduce_ops([("/device:TPU:0", [op(FUSION, 0, 10)], [])],
+                           [], 2.5, [], PEAK)
+    report = SimpleNamespace(stats=[
+        SimpleNamespace(prompt_len=p, new_tokens=n)
+        for p, n in ((64, 200), (80, 512), (16, 33))])
+
+    def mfu(chips):
+        obs = Observed(cell=dataclasses.replace(cell, chips=chips), slots=16,
+                       setup_s=1.0, window_s=2.5, segments=[], done=[],
+                       device_kind="TPU v5 lite", trace=red,
+                       traced_report=report)
+        return reader("mfu").read(obs)
+
+    assert 0 < mfu(1) <= 100
+    assert mfu(4) == mfu(1) / 4
+
+
+@pytest.mark.parametrize("name", ["decode_2of4", "engine_spans_2of4"])
+def test_recorded_traces_read_as_before(name):
+    """The recorded one-device traces read exactly what the reduction
+    read before it reported per chip
+    (``fixtures/one_device_readings.json``)."""
+    want = json.loads((FIXTURE / "one_device_readings.json").read_text())
+    devices, host = trace.load_ops(FIXTURE / f"{name}.xplane.pb")
+    ops = devices[0][1]
+    first = min(o.start_ns for o in ops)
+    last = max(o.start_ns + o.dur_ns for o in ops)
+    red = trace.reduce_ops(devices, host, (last - first) * 1e-9,
+                           trace.load_classes(KERNELS), PEAK)
+    got = dict(readings(red), busy_s=red.busy_s, breakdown=red.breakdown())
+    assert json.loads(json.dumps(got)) == want[name]
