@@ -1,5 +1,7 @@
 """mistral-large-123b [dense] — GQA kv=8.
-[hf:mistralai/Mistral-Large-Instruct-2407; unverified]"""
+[hf:mistralai/Mistral-Large-Instruct-2407 config.json: 88 layers, hidden
+12288, 96 / 8 heads of 128, SwiGLU 28672, vocab 32768 untied, RMSNorm eps
+1e-5, RoPE theta 1e6]"""
 
 import dataclasses
 
@@ -16,6 +18,8 @@ CONFIG = ModelConfig(
     d_ff=28672,
     vocab_size=32768,
     act="swiglu",
+    rope_theta=1_000_000.0,
+    rms_norm_eps=1e-5,
 )
 
 SMOKE_CONFIG = dataclasses.replace(

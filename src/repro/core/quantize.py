@@ -50,6 +50,7 @@ calibration workflow, and the sharded pmax/psum/dequantize ordering).
 from __future__ import annotations
 
 import contextlib
+import functools
 import warnings
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -199,6 +200,16 @@ def _cast_quantized(x32: jax.Array, dtype) -> jax.Array:
     return q.astype(dt)
 
 
+@jax.jit
+def _channel_absmax(w: jax.Array) -> jax.Array:
+    return jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-2)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _cast_to_scale(w: jax.Array, scale: jax.Array, dtype) -> jax.Array:
+    return _cast_quantized(w.astype(jnp.float32) / scale[..., None, :], dtype)
+
+
 def quantize_per_channel(
     w: jax.Array, dtype=jnp.int8
 ) -> Tuple[jax.Array, jax.Array]:
@@ -211,16 +222,21 @@ def quantize_per_channel(
     absolute error bounded by the dtype's step at the channel absmax
     (``absmax/127`` for int8; one fp8 ulp at absmax — tighter for most
     of the distribution, since fp8 steps shrink toward zero).
+
+    The two passes over ``w`` are jitted, so their float32 copies fuse
+    away and a stacked leaf quantizes beside its narrow result alone (at
+    Mistral-Large's widths an eager pass held two float32 copies of a
+    1.9 GB bfloat16 leaf per chip beside the whole tree).  The scale
+    between them stays eager: jitted, ``absmax / qmax`` would become a
+    multiply by the constant's reciprocal and move some values a step.
     """
     dt = canonical_qdtype(dtype)
-    w32 = w.astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(w32), axis=-2)                  # (..., O)
+    absmax = _channel_absmax(w)                               # (..., O)
     # floor AFTER the division: tiny/qmax is a denormal that XLA may
     # flush to zero, which would turn all-zero channels into 0/0 = NaN
     scale = jnp.maximum(absmax / QUANT_DTYPES[dt],
                         jnp.finfo(jnp.float32).tiny)
-    q = _cast_quantized(w32 / scale[..., None, :], dt)
-    return q, scale.astype(jnp.float32)
+    return _cast_to_scale(w, scale, dt), scale.astype(jnp.float32)
 
 
 def dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:

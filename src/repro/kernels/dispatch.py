@@ -2030,9 +2030,15 @@ def gate_up_matmul(
     # the concat collapse (one GEMM over 2o, activation read once from
     # HBM) only pays for itself when a kernel actually runs it; on the
     # jnp tier the per-call O(ke*2o) weight concat costs more than the
-    # decode-shape GEMM it feeds, so two plain XLA GEMMs win there
+    # decode-shape GEMM it feeds, so two plain XLA GEMMs win there.
+    # Under shard_map both weights are split along o over the model
+    # axis, and [Wg | Wu] split the same way holds other columns than
+    # either: every call would move the weights between chips (an
+    # all-to-all per weight, then collective-permutes to slice the
+    # result apart), so sharded pairs run as two shard_map GEMMs
     cat = (_concat_gate_up(params_g, params_u, mode_g)
            if pair_ok and decision is not None and decision.uses_kernel
+           and not decision.uses_shard_map
            else None)
     if cat is not None:
         y2 = sparse_matmul(x2, cat, cfg, constrain_fn=g, dispatch=dcfg,

@@ -30,6 +30,7 @@ from jax.tree_util import DictKey, SequenceKey
 
 from repro.core.sparse_linear import COLUMN_PARALLEL, ROW_PARALLEL
 from repro.models.config import ModelConfig, ShapeConfig
+from repro.models.paged import paged_cache_shardings
 from repro.models.pjit_utils import AxisEnv
 
 # canonical column/row-parallel name sets live in repro.core.sparse_linear
@@ -208,6 +209,13 @@ class ShardingRules:
             return NamedSharding(mesh, P(*((None,) * len(shape))))
 
         return jax.tree_util.tree_map_with_path(fn, caches)
+
+    def paged_cache_shardings(self, caches) -> Any:
+        """Paged decode caches (``repro.models.paged``), placed by the
+        models layer's own rule (:func:`repro.models.paged.
+        paged_cache_shardings`): KV heads over model where whole heads
+        divide it, blocks and positions whole, scales like their pool."""
+        return paged_cache_shardings(self.env, caches)
 
 
 def train_in_shardings(rules: ShardingRules, params_shapes, opt_shapes, batch_shapes,

@@ -44,6 +44,7 @@ class ModelConfig:
     act: str = "swiglu"         # swiglu | gelu
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
+    rms_norm_eps: float = 1e-6  # added to the mean square in every RMSNorm
     # --- modality frontend (stub per assignment) ---
     frontend: str = "none"      # none | audio_frames | vision_patches
     num_patches: int = 0        # vlm: image tokens per sample

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
@@ -16,12 +17,14 @@ Params = Dict[str, Any]
 
 
 # ---------------------------------------------------------------- norms
-_RMS_EPS = 1e-6
+_RMS_EPS = 1e-6     # ModelConfig.rms_norm_eps's default
 
 
-@jax.custom_vjp
-def rms_norm(x: jax.Array, gamma: jax.Array) -> jax.Array:
-    """RMSNorm with a hand-written VJP.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def rms_norm(x: jax.Array, gamma: jax.Array,
+             eps: float = _RMS_EPS) -> jax.Array:
+    """RMSNorm with a hand-written VJP; ``eps`` is the model's own
+    (``ModelConfig.rms_norm_eps``).
 
     Autodiff through the fp32-upcast norm generates ~15 fp32 (B,T,d)
     intermediates per call (measured as a top byte dominator at 88 layers
@@ -29,19 +32,19 @@ def rms_norm(x: jax.Array, gamma: jax.Array) -> jax.Array:
     """
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return ((xf * jax.lax.rsqrt(var + _RMS_EPS))
+    return ((xf * jax.lax.rsqrt(var + eps))
             * (1.0 + gamma.astype(jnp.float32))).astype(x.dtype)
 
 
-def _rms_fwd(x, gamma):
+def _rms_fwd(x, gamma, eps=_RMS_EPS):
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    r = jax.lax.rsqrt(var + _RMS_EPS)                 # (..., 1) tiny
+    r = jax.lax.rsqrt(var + eps)                      # (..., 1) tiny
     y = ((xf * r) * (1.0 + gamma.astype(jnp.float32))).astype(x.dtype)
     return y, (x, gamma, r)
 
 
-def _rms_bwd(res, dy):
+def _rms_bwd(eps, res, dy):
     x, gamma, r = res
     xf = x.astype(jnp.float32)
     g = dy.astype(jnp.float32) * (1.0 + gamma.astype(jnp.float32))

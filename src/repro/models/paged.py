@@ -31,11 +31,13 @@ scratch block 0, which no table row ever references for a live position.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.quantize import canonical_qdtype, quantize_rows
 from repro.core.sparse_linear import apply_linear
@@ -43,6 +45,7 @@ from repro.core.sparse_linear import apply_linear
 from .attention import NEG_INF, _grouped, _project_qkv
 from .config import ModelConfig
 from .layers import embed
+from .pjit_utils import AxisEnv, axis_env
 from .ssm import decode_mamba_block, init_ssm_cache
 from .transformer import build_layout, cached_stack
 
@@ -50,6 +53,7 @@ Params = Dict[str, Any]
 
 __all__ = [
     "init_paged_caches",
+    "paged_cache_shardings",
     "paged_decode_step",
     "paged_prefill_chunk",
     "reset_slot_state",
@@ -94,6 +98,66 @@ def init_paged_caches(
             )
         caches.append(stage_c)
     return caches
+
+
+# the (heads, slots) dims of each paged-cache leaf: pools and their
+# int8/fp8 scales keep blocks and positions whole; per-slot SSM state
+# ``(count, repeat, slots, ...)`` puts its slots over the batch axes
+_CACHE_DIMS = {"k": (-2, None), "v": (-2, None),
+               "k_scale": (-1, None), "v_scale": (-1, None),
+               "state": (3, 2), "conv": (None, 2)}
+
+
+def heads_spec(env: AxisEnv, shape, heads_dim: Optional[int] = None,
+               batch_dim: Optional[int] = None) -> P:
+    """Heads at ``heads_dim`` over the model axis where whole heads
+    divide it, ``batch_dim`` over the batch axes where it divides them,
+    every other dim whole.  Trailing whole dims are left out of the
+    spec, as a compiled program states its outputs' placement: a pool
+    born under this spec and one a paged step returns then key the same
+    compiled step."""
+    mesh, spec = env.mesh, [None] * len(shape)
+    if (heads_dim is not None
+            and shape[heads_dim] % mesh.shape[env.model_axis] == 0):
+        spec[heads_dim] = env.model_axis
+    if (batch_dim is not None and shape[batch_dim]
+            % math.prod(mesh.shape[a] for a in env.batch_axes) == 0):
+        spec[batch_dim] = env.physical("batch")
+    while spec and spec[-1] is None:
+        spec.pop()
+    return P(*spec)
+
+
+def paged_cache_shardings(env: AxisEnv, caches) -> Any:
+    """Where paged caches (:func:`init_paged_caches`, one layer's or the
+    stacked tree) live on ``env``'s mesh: a pool's KV heads over the
+    model axis where whole heads divide it, as the column-parallel
+    ``wk``/``wv`` shard them, and its scales like their pool; SSM state
+    by its heads and slots.  The engine births its pools under this and
+    both paged steps take and return them so."""
+    def fn(path, leaf):
+        heads, batch = _CACHE_DIMS.get(getattr(path[-1], "key", None),
+                                       (None, None))
+        return NamedSharding(env.mesh,
+                             heads_spec(env, leaf.shape, heads, batch))
+
+    return jax.tree_util.tree_map_with_path(fn, caches)
+
+
+def _on_heads(env: Optional[AxisEnv], x: jax.Array) -> jax.Array:
+    """(B, T, H, D) -> the same, heads over model, batch over DP."""
+    if env is None:
+        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(
+        env.mesh, heads_spec(env, x.shape, 2, batch_dim=0)))
+
+
+def _place_pools(env: Optional[AxisEnv], caches):
+    """Caches under :func:`paged_cache_shardings`; no-op off-mesh."""
+    if env is None:
+        return caches
+    return jax.lax.with_sharding_constraint(
+        caches, paged_cache_shardings(env, caches))
 
 
 def _write_kv(cache, k_new, v_new, phys, off, kv_qdtype):
@@ -141,7 +205,13 @@ def _paged_attention(
     kv_qdtype: Optional[str],
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     b, t, _ = x.shape
-    q, k_new, v_new = _project_qkv(p, x, cfg, positions)
+    # q, the new KV rows, the pool and its gather all keep their heads
+    # where the column-parallel projections leave them: each chip writes,
+    # gathers and attends its own heads, and no pool leaves its chip
+    env = axis_env()
+    q, k_new, v_new = (_on_heads(env, a)
+                       for a in _project_qkv(p, x, cfg, positions))
+    cache = _place_pools(env, cache)
     blk = positions // block_len
     off = positions % block_len
     phys = jnp.take_along_axis(table, blk, axis=1)          # (B, T)
@@ -152,8 +222,10 @@ def _paged_attention(
         k_new.reshape(b * t, cfg.num_kv_heads, cfg.head_dim),
         v_new.reshape(b * t, cfg.num_kv_heads, cfg.head_dim),
         phys, off, kv_qdtype)
+    new_cache = _place_pools(env, new_cache)
 
-    k, v = _gather_kv(new_cache, table, kv_qdtype, x.dtype)
+    k, v = (_on_heads(env, a)
+            for a in _gather_kv(new_cache, table, kv_qdtype, x.dtype))
     qg = _grouped(q, cfg)                                   # (B,Hkv,G,T,D)
     scale = cfg.head_dim**-0.5
     s = jnp.einsum(
@@ -253,7 +325,8 @@ def paged_decode_step(
                 block_len=block_len, kv_qdtype=kv_qdtype)
         return _masked_decode_mamba(lp["mixer"]["mamba"], h, lc, active, cfg)
 
-    return cached_stack(params, caches, x, cfg, mixer)
+    logits, caches = cached_stack(params, caches, x, cfg, mixer)
+    return logits, _place_pools(axis_env(), caches)
 
 
 @partial(jax.jit, static_argnames=("cfg", "block_len", "kv_qdtype"))
@@ -294,7 +367,8 @@ def paged_prefill_chunk(
         return _prefill_mamba_slot(lp["mixer"]["mamba"], h, lc, n_valid,
                                    slot_idx, cfg)
 
-    return cached_stack(params, caches, x, cfg, mixer)
+    logits, caches = cached_stack(params, caches, x, cfg, mixer)
+    return logits, _place_pools(axis_env(), caches)
 
 
 def reset_slot_state(caches, slot_index: int):

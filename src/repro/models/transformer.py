@@ -141,7 +141,7 @@ def init_params(key, cfg: ModelConfig) -> Params:
 
 # ------------------------------------------------------------------ apply
 def _apply_slot(p: Params, x: jax.Array, slot: Slot, cfg: ModelConfig) -> jax.Array:
-    h = rms_norm(x, p["norm1"]["gamma"])
+    h = rms_norm(x, p["norm1"]["gamma"], cfg.rms_norm_eps)
     if slot.mixer == "attn":
         x = x + attention_block(p["mixer"], h, cfg, is_global=True)
     elif slot.mixer == "attn_local":
@@ -149,7 +149,7 @@ def _apply_slot(p: Params, x: jax.Array, slot: Slot, cfg: ModelConfig) -> jax.Ar
     else:
         x = x + mamba_block(p["mixer"]["mamba"], h, cfg)
     if slot.ffn != "none":
-        h = rms_norm(x, p["norm2"]["gamma"])
+        h = rms_norm(x, p["norm2"]["gamma"], cfg.rms_norm_eps)
         if slot.ffn == "moe":
             x = x + apply_moe(p["ffn"], h, cfg)
         else:
@@ -207,7 +207,7 @@ def forward(
         x = embed(params["embed"], tokens)
     x = constrain(x, "batch", None, None)
     x = apply_stack(params, x, cfg)
-    x = rms_norm(x, params["final_norm"]["gamma"])
+    x = rms_norm(x, params["final_norm"]["gamma"], cfg.rms_norm_eps)
     unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
     logits = x @ unembed.astype(x.dtype)
     return constrain(logits, "batch", None, "model")
@@ -262,11 +262,11 @@ def cached_stack(
                 sp, sc = sb_params[f"slot{j}"], sb_cache[f"slot{j}"]
 
                 def one(x, lp, lc, slot=slot):
-                    h = rms_norm(x, lp["norm1"]["gamma"])
+                    h = rms_norm(x, lp["norm1"]["gamma"], cfg.rms_norm_eps)
                     o, c = mixer_fn(slot, lp, lc, h)
                     x = x + o
                     if slot.ffn != "none":
-                        h = rms_norm(x, lp["norm2"]["gamma"])
+                        h = rms_norm(x, lp["norm2"]["gamma"], cfg.rms_norm_eps)
                         if slot.ffn == "moe":
                             x = x + apply_moe(lp["ffn"], h, cfg)
                         else:
@@ -290,7 +290,7 @@ def cached_stack(
 
         x, ncs = jax.lax.scan(super_block, x, (stage_params, stage_cache))
         new_caches.append(ncs)
-    x = rms_norm(x, params["final_norm"]["gamma"])
+    x = rms_norm(x, params["final_norm"]["gamma"], cfg.rms_norm_eps)
     unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
     logits = x @ unembed.astype(x.dtype)
     return logits, new_caches

@@ -132,13 +132,44 @@ class Engine:
         self.num_blocks = (self.spec.kv_blocks
                            if self.spec.kv_blocks is not None
                            else self.spec.default_kv_blocks())
+        self._placed_caches = None    # on a mesh: the jitted, placed birth
 
-    def _fresh_caches(self):
+    def _init_caches(self):
         from repro.models.paged import init_paged_caches
         # +1: physical block 0 is the scratch target for masked writes
         return init_paged_caches(self.cfg, self.num_blocks + 1,
                                  self.spec.block_len, self.spec.slots,
                                  kv_qdtype=self.spec.kv_qdtype)
+
+    def _fresh_caches(self):
+        """A new KV pool.  On a mesh it is born placed as the paged steps
+        take and return it (``repro.models.paged.paged_cache_shardings``),
+        so every segment runs the same programs and no chip holds
+        another's heads."""
+        env = self.prepared.axis_env
+        if env is None:
+            return self._init_caches()
+        if self._placed_caches is None:
+            import jax
+
+            from repro.models.paged import paged_cache_shardings
+            placed = paged_cache_shardings(
+                env, jax.eval_shape(self._init_caches))
+            self._placed_caches = jax.jit(self._init_caches,
+                                          out_shardings=placed)
+        return self._placed_caches()
+
+    def _feed(self, *arrays: np.ndarray) -> tuple:
+        """Host arrays for a paged step: replicated over the mesh in one
+        transfer (every chip reads the whole block table, tokens and
+        positions), or as ``jnp.asarray`` makes them off-mesh."""
+        import jax
+        import jax.numpy as jnp
+        if self.prepared.mesh is None:
+            return tuple(jnp.asarray(a) for a in arrays)
+        from jax.sharding import NamedSharding, PartitionSpec
+        return tuple(jax.device_put(arrays, NamedSharding(
+            self.prepared.mesh, PartitionSpec())))
 
     def kv_bytes(self) -> int:
         """HBM footprint of the block pools (the budget the scheduler
@@ -148,7 +179,7 @@ class Engine:
         import math
 
         import jax
-        shapes = jax.eval_shape(self._fresh_caches)
+        shapes = jax.eval_shape(self._init_caches)
         return sum(math.prod(x.shape) * x.dtype.itemsize
                    for x in jax.tree.leaves(shapes))
 
@@ -229,12 +260,11 @@ class Engine:
             with TraceAnnotation("engine.prefill", rid=st.req.rid, tokens=c):
                 if not sched.ensure_blocks(s, st.prefill_off + c - 1):
                     return
-                tok = jnp.asarray(
+                tok, table = self._feed(np.asarray(
                     st.req.prompt[st.prefill_off:st.prefill_off + c],
-                    jnp.int32)[None, :]
+                    np.int32)[None, :], sched.table[s:s + 1])
                 logits, caches = paged_prefill_chunk(
-                    params, caches, tok, jnp.int32(st.prefill_off),
-                    jnp.asarray(sched.table[s:s + 1]),
+                    params, caches, tok, jnp.int32(st.prefill_off), table,
                     jnp.int32(c), jnp.int32(s), self.cfg,
                     spec.block_len, spec.kv_qdtype)
                 prefill_chunks += 1
@@ -280,8 +310,8 @@ class Engine:
                     feed[s, 0] = st.out[-1]
                     positions[s] = st.pos
                     active[s] = True
-                feed, positions = jnp.asarray(feed), jnp.asarray(positions)
-                table, active = jnp.asarray(sched.table), jnp.asarray(active)
+                feed, positions, table, active = self._feed(
+                    feed, positions, sched.table, active)
             with TraceAnnotation("engine.dispatch", slots=len(ready)):
                 logits, caches = paged_decode_step(
                     params, caches, feed, positions, table, active,
