@@ -17,11 +17,19 @@ Finished requests retire independently (ragged lengths), their blocks
 return to the pool, and the slot admits the next arrival on the next
 iteration — no stream ever waits for the whole batch to drain, which is
 exactly what the lockstep loop (``repro.serving.baseline``) cannot do.
+
+Sampled tokens stay on the device: each slot's last token is one
+``(slots, 1)`` array that the next decode step reads as its feed.  The
+schedule never reads a token's value (retirement counts tokens), so the
+host reads each step's tokens one step late, after it has dispatched
+the next: the chip always has a step queued while the host works.  A
+request's stats are finished when its last token reaches the host.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, List, Optional, Sequence
 
@@ -75,6 +83,8 @@ class ServingReport:
     evictions: int
     max_blocks_in_use: int
     num_blocks: int
+    # decode steps dispatched while the previous step's tokens were unread
+    overlapped_steps: int = 0
 
     @property
     def p50_latency_s(self) -> float:
@@ -110,7 +120,34 @@ class ServingReport:
                 f"p95 queue {queue * 1e3:.0f}ms / "
                 f"p95 first token {ttft * 1e3:.0f}ms, "
                 f"{self.evictions} eviction(s), "
+                f"{self.overlapped_steps}/{self.decode_calls} decode steps "
+                f"overlapped, "
                 f"peak {self.max_blocks_in_use}/{self.num_blocks} blocks)")
+
+
+@functools.lru_cache(maxsize=None)
+def _token_programs(placement):
+    """The two device programs that keep sampled tokens on the device,
+    jitted once per ``placement`` of the token array, which their
+    results keep: the decode step and they themselves then see one
+    signature every step, and never retrace."""
+    import jax
+    import jax.numpy as jnp
+
+    def first(tokens, logits, slot, n_valid):
+        """Slot ``slot``'s first token: the argmax of its prefill
+        chunk's last valid logit."""
+        last = jax.lax.dynamic_index_in_dim(logits[0], n_valid - 1,
+                                            keepdims=False)
+        return tokens.at[slot, 0].set(jnp.argmax(last).astype(tokens.dtype))
+
+    def following(logits, tokens, active):
+        """Each decoding slot's next token; other slots keep theirs."""
+        nxt = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
+        return jnp.where(active[:, None], nxt, tokens)
+
+    return (jax.jit(first, out_shardings=placement),
+            jax.jit(following, out_shardings=placement))
 
 
 class Engine:
@@ -132,7 +169,7 @@ class Engine:
         self.num_blocks = (self.spec.kv_blocks
                            if self.spec.kv_blocks is not None
                            else self.spec.default_kv_blocks())
-        self._placed_caches = None    # on a mesh: the jitted, placed birth
+        self._placed_caches = None    # the jitted, placed birth
 
     def _init_caches(self):
         from repro.models.paged import init_paged_caches
@@ -142,27 +179,33 @@ class Engine:
                                  kv_qdtype=self.spec.kv_qdtype)
 
     def _fresh_caches(self):
-        """A new KV pool.  On a mesh it is born placed as the paged steps
-        take and return it (``repro.models.paged.paged_cache_shardings``),
-        so every segment runs the same programs and no chip holds
-        another's heads."""
-        env = self.prepared.axis_env
-        if env is None:
-            return self._init_caches()
+        """A new KV pool, born committed where the paged steps return it,
+        so every segment runs the same programs: on a mesh placed as the
+        steps take it (``repro.models.paged.paged_cache_shardings``), so
+        no chip holds another's heads; off a mesh where a feed goes."""
         if self._placed_caches is None:
             import jax
-
-            from repro.models.paged import paged_cache_shardings
-            placed = paged_cache_shardings(
-                env, jax.eval_shape(self._init_caches))
+            env = self.prepared.axis_env
+            if env is None:
+                placed = self._feed_placement()
+            else:
+                from repro.models.paged import paged_cache_shardings
+                placed = paged_cache_shardings(
+                    env, jax.eval_shape(self._init_caches))
             self._placed_caches = jax.jit(self._init_caches,
                                           out_shardings=placed)
         return self._placed_caches()
 
+    def _feed_placement(self):
+        """Where :meth:`_feed` puts an array."""
+        return self._feed(np.zeros((), np.int32))[0].sharding
+
     def _feed(self, *arrays: np.ndarray) -> tuple:
         """Host arrays for a paged step: replicated over the mesh in one
         transfer (every chip reads the whole block table, tokens and
-        positions), or as ``jnp.asarray`` makes them off-mesh."""
+        positions), or as ``jnp.asarray`` makes them off-mesh.  A feed
+        may alias its host array until the step has run, so no array
+        passed here may change after the call."""
         import jax
         import jax.numpy as jnp
         if self.prepared.mesh is None:
@@ -194,10 +237,13 @@ class Engine:
         span, on the device trace's clock when a profiler trace is
         running (about a microsecond each when none is): ``engine.run``
         holds one ``engine.iter`` per work iteration, which holds
-        ``engine.admit``, ``engine.prefill`` (with its ``engine.sync``
-        on the first token), ``engine.decode_feed``, ``engine.dispatch``
-        and ``engine.sync``; ``engine.retire`` marks each retirement.
-        Spans of one request carry its ``rid``.
+        ``engine.admit``, ``engine.prefill``, ``engine.decode_feed``,
+        ``engine.dispatch`` and ``engine.sync``; ``engine.retire`` marks
+        each retirement.  A decoding iteration's ``engine.sync`` comes
+        after its dispatch and reads the previous step's tokens (its
+        ``step``); an iteration that dispatches no decode step, or
+        leaves no request running, reads everything at its end.  Spans
+        of one request carry its ``rid``.
         """
         from jax.profiler import TraceAnnotation
 
@@ -205,6 +251,7 @@ class Engine:
             return self._serve(requests, max_iters, collect_tokens)
 
     def _serve(self, requests, max_iters, collect_tokens) -> ServingReport:
+        import jax
         import jax.numpy as jnp
         from jax.profiler import TraceAnnotation
 
@@ -218,6 +265,12 @@ class Engine:
                                block_len=spec.block_len,
                                admission=spec.admission)
         caches = self._fresh_caches()
+        # each slot's last token, on the device: the next decode's feed,
+        # committed where a feed goes, as the token programs return it
+        placement = self._feed_placement()
+        tokens = jax.device_put(np.zeros((spec.slots, 1), np.int32),
+                                placement)
+        first_token, next_tokens = _token_programs(placement)
         arrivals = sorted(requests, key=lambda r: (r.arrival, r.rid))
         n = len(arrivals)
         if max_iters is None:
@@ -226,7 +279,11 @@ class Engine:
             max_iters = 64 + 16 * sum(
                 len(r.prompt) + r.max_new_tokens for r in arrivals)
         stats: List[RequestStats] = []
-        prefill_chunks = decode_calls = 0
+        # token arrays not yet read: (tokens, [(slot, state)], decode
+        # step that made them, 0 for a prefill's first token)
+        pending = []
+        finishing = {}    # rid -> iteration it retired in
+        prefill_chunks = decode_calls = overlapped = 0
         ai = 0
         it = 0        # simulated clock (fast-forwards over idle gaps)
         work = 0      # iterations that had work — what the guard counts
@@ -235,21 +292,51 @@ class Engine:
         def _retire(s: int):
             with TraceAnnotation("engine.retire", rid=sched.slots[s].req.rid):
                 st = sched.retire(s)
-                now = time.perf_counter()
-                lat = now - st.enqueue_wall
-                stats.append(RequestStats(
-                    rid=st.req.rid, prompt_len=len(st.req.prompt),
-                    new_tokens=len(st.out),
-                    tokens=tuple(st.out) if collect_tokens else (),
-                    arrival=st.req.arrival, done_iter=it,
-                    latency_s=lat,
-                    queue_s=st.admit_wall - st.enqueue_wall,
-                    ttft_s=st.first_token_wall - st.enqueue_wall,
-                    tokens_per_s=len(st.out) / lat if lat > 0 else 0.0))
+                finishing[st.req.rid] = it
+
+        def _finish(st, now: float):
+            lat = now - st.enqueue_wall
+            stats.append(RequestStats(
+                rid=st.req.rid, prompt_len=len(st.req.prompt),
+                new_tokens=len(st.out),
+                tokens=tuple(st.out) if collect_tokens else (),
+                arrival=st.req.arrival, done_iter=finishing.pop(st.req.rid),
+                latency_s=lat,
+                queue_s=st.admit_wall - st.enqueue_wall,
+                ttft_s=st.first_token_wall - st.enqueue_wall,
+                tokens_per_s=len(st.out) / lat if lat > 0 else 0.0))
+
+        def _produced(arr, entries, step=0):
+            """``arr`` holds a new token of each ``(slot, state)``: start
+            its copy to the host, read it later."""
+            arr.copy_to_host_async()
+            pending.append((arr, entries, step))
+
+        def _sync(keep=None):
+            """Read every pending token array but ``keep`` to the host;
+            stamp first tokens, and finish the requests whose last token
+            this was."""
+            nonlocal pending
+            read = [p for p in pending if p[0] is not keep]
+            if not read:
+                return
+            pending = [p for p in pending if p[0] is keep]
+            with TraceAnnotation("engine.sync",
+                                 step=max(p[2] for p in read)):
+                values = jax.device_get([p[0] for p in read])
+            now = time.perf_counter()
+            for (_, entries, _), v in zip(read, values):
+                for s, st in entries:
+                    st.out.append(int(v[s, 0]))
+                    if st.first_token_wall is None:
+                        st.first_token_wall = now
+                    if (st.req.rid in finishing
+                            and len(st.out) == st.emitted):
+                        _finish(st, now)
 
         def _prefill():
             """One prefill chunk for the oldest prefilling request."""
-            nonlocal caches, prefill_chunks
+            nonlocal caches, tokens, prefill_chunks
             pre = [s for s in sched.running
                    if sched.slots[s].state == "prefill"]
             if not pre:
@@ -260,34 +347,35 @@ class Engine:
             with TraceAnnotation("engine.prefill", rid=st.req.rid, tokens=c):
                 if not sched.ensure_blocks(s, st.prefill_off + c - 1):
                     return
+                # the table is copied: the step may run after the
+                # scheduler has changed it
                 tok, table = self._feed(np.asarray(
                     st.req.prompt[st.prefill_off:st.prefill_off + c],
-                    np.int32)[None, :], sched.table[s:s + 1])
+                    np.int32)[None, :], sched.table[s:s + 1].copy())
+                n_valid, slot = jnp.int32(c), jnp.int32(s)
                 logits, caches = paged_prefill_chunk(
                     params, caches, tok, jnp.int32(st.prefill_off), table,
-                    jnp.int32(c), jnp.int32(s), self.cfg,
-                    spec.block_len, spec.kv_qdtype)
+                    n_valid, slot, self.cfg, spec.block_len, spec.kv_qdtype)
                 prefill_chunks += 1
                 st.prefill_off += c
                 if st.prefill_off < len(st.req.prompt):
                     return
                 st.state = "decode"
                 st.pos = len(st.req.prompt)
-                first = jnp.argmax(logits[0, c - 1])
-                with TraceAnnotation("engine.sync"):
-                    st.out.append(int(first))
-                if st.first_token_wall is None:
-                    st.first_token_wall = time.perf_counter()
-                if len(st.out) >= st.req.max_new_tokens:
+                tokens = first_token(tokens, logits, slot, n_valid)
+                st.emitted = 1
+                _produced(tokens, [(s, st)])
+                if st.emitted >= st.req.max_new_tokens:
                     _retire(s)
 
-        def _decode():
-            """One batched decode step over every decode-state slot."""
-            nonlocal caches, decode_calls
+        def _decode() -> bool:
+            """One batched decode step over every decode-state slot;
+            False when none was dispatched."""
+            nonlocal caches, tokens, decode_calls, overlapped
             dec = [s for s in sched.running
                    if sched.slots[s].state == "decode"]
             if not dec:
-                return
+                return False
             with TraceAnnotation("engine.decode_feed"):
                 ready = []
                 for s in dec:
@@ -301,34 +389,36 @@ class Engine:
                 ready = [s for s in ready if sched.slots[s] is not None
                          and sched.slots[s].state == "decode"]
                 if not ready:
-                    return
-                feed = np.zeros((spec.slots, 1), np.int32)
+                    return False
                 positions = np.zeros((spec.slots,), np.int32)
                 active = np.zeros((spec.slots,), bool)
                 for s in ready:
-                    st = sched.slots[s]
-                    feed[s, 0] = st.out[-1]
-                    positions[s] = st.pos
+                    positions[s] = sched.slots[s].pos
                     active[s] = True
-                feed, positions, table, active = self._feed(
-                    feed, positions, sched.table, active)
-            with TraceAnnotation("engine.dispatch", slots=len(ready)):
+                positions, table, active = self._feed(
+                    positions, sched.table.copy(), active)
+            with TraceAnnotation("engine.dispatch", slots=len(ready),
+                                 step=decode_calls + 1):
                 logits, caches = paged_decode_step(
-                    params, caches, feed, positions, table, active,
+                    params, caches, tokens, positions, table, active,
                     self.cfg, spec.block_len, spec.kv_qdtype)
+                tokens = next_tokens(logits, tokens, active)
                 decode_calls += 1
-                nxt = jnp.argmax(logits[:, 0], axis=-1)
-            with TraceAnnotation("engine.sync"):
-                nxt = np.asarray(nxt)
-            for s in ready:
-                st = sched.slots[s]
-                st.out.append(int(nxt[s]))
+                if any(p[2] for p in pending):
+                    overlapped += 1
+            entries = [(s, sched.slots[s]) for s in ready]
+            for s, st in entries:
                 st.pos += 1
-                if len(st.out) >= st.req.max_new_tokens:
+                st.emitted += 1
+                if st.emitted >= st.req.max_new_tokens:
                     _retire(s)
+            _produced(tokens, entries, decode_calls)
+            # the previous step's tokens; all of them once none is running
+            _sync(keep=tokens if sched.running else None)
+            return True
 
         with self.prepared.activate():
-            while len(stats) < n:
+            while len(stats) + len(finishing) < n:    # until all retire
                 # guard on WORK iterations, not the simulated clock:
                 # idle fast-forwarding jumps `it` to absolute arrival
                 # timestamps, which a sparse trace can push past any
@@ -351,10 +441,12 @@ class Engine:
                         for s in sched.admit_ready(wall=time.perf_counter()):
                             caches = reset_slot_state(caches, s)
                     _prefill()
-                    _decode()
+                    if not _decode():
+                        _sync()
                 it += 1
                 work += 1
 
+        assert not pending and not finishing, (len(pending), finishing)
         return ServingReport(
             stats=sorted(stats, key=lambda s_: s_.rid),
             total=n, completed=len(stats),
@@ -364,4 +456,5 @@ class Engine:
             iterations=work,
             evictions=sched.evictions,
             max_blocks_in_use=sched.max_blocks_in_use,
-            num_blocks=self.num_blocks)
+            num_blocks=self.num_blocks,
+            overlapped_steps=overlapped)

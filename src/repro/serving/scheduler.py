@@ -10,6 +10,8 @@ Host-side, numpy-only state (the device never sees a Python branch):
   ``j <= pos`` attention mask, so a stale or zero entry is harmless);
 - per-slot :class:`SlotState` tracking prefill progress, decode
   position, and generated tokens — ragged lengths retire independently.
+  Retirement counts the tokens the device has produced (``emitted``),
+  never their values, so the engine may read ``out`` a step late.
 
 Admission policies:
 
@@ -62,7 +64,8 @@ class SlotState:
     state: str = "prefill"        # "prefill" | "decode"
     prefill_off: int = 0          # prompt tokens already prefilled
     pos: int = 0                  # decode: position of the next write
-    out: List[int] = dataclasses.field(default_factory=list)
+    emitted: int = 0              # tokens produced on the device
+    out: List[int] = dataclasses.field(default_factory=list)  # ...read back
     enqueue_wall: float = 0.0
     enqueue_iter: float = 0.0
     # first admission and first token: kept across preemption, since
@@ -162,6 +165,7 @@ class PagedScheduler:
             st.state = "prefill"
             st.prefill_off = 0
             st.pos = 0
+            st.emitted = 0
             st.out = []
             if st.admit_wall is None:
                 st.admit_wall = wall

@@ -6,9 +6,10 @@
 ``engine.dispatch`` / ``engine.sync`` / ``engine.retire``).  A run under
 ``jax.profiler.trace`` must hold every span, one ``engine.iter`` per
 work iteration the report counts, and exactly one ``engine.sync`` after
-the ``engine.dispatch`` of each decoding iteration.  Each request's
-stamps must read ``0 <= queue_s <= ttft_s <= latency_s``, also when
-preemption makes it prefill twice.
+the ``engine.dispatch`` of each decoding iteration, which reads the
+previous step's tokens.  Each request's stamps must read
+``0 <= queue_s <= ttft_s <= latency_s``, also when preemption makes it
+prefill twice.
 """
 
 import tempfile
@@ -127,26 +128,36 @@ def test_one_iter_span_per_work_iteration(traced):
 
 def test_each_decoding_iteration_syncs_once_after_dispatch(traced):
     report, spans = traced
+    iters = _named(spans, "engine.iter")
     decoding = 0
-    for it in _named(spans, "engine.iter"):
+    for i, it in enumerate(iters):
         kids = [c.name for c in it.children]
         if "engine.dispatch" not in kids:
-            assert "engine.sync" not in kids
+            # nothing dispatched: what is pending is read at the end
+            assert kids.count("engine.sync") <= 1
+            assert "engine.sync" not in kids[:-1]
             continue
         decoding += 1
         assert kids.count("engine.dispatch") == 1
         assert kids.count("engine.sync") == 1
         assert kids.count("engine.decode_feed") == 1
-        # feed, then dispatch, then the host waits on the argmax
+        # feed, then dispatch, then the host reads the previous step
         order = [k for k in kids if k in ("engine.decode_feed",
                                           "engine.dispatch", "engine.sync")]
         assert order == ["engine.decode_feed", "engine.dispatch",
                          "engine.sync"]
+        (dispatch,) = [c for c in it.children if c.name == "engine.dispatch"]
+        (sync,) = [c for c in it.children if c.name == "engine.sync"]
+        # ...and this step's too where none is left running: before the
+        # idle stretch and at the end of the run
+        drains = (i + 1 == len(iters)
+                  or iters[i + 1].stats["it"] > it.stats["it"] + 1)
+        lag = 0 if drains else 1
+        assert sync.stats["step"] == dispatch.stats["step"] - lag
     assert decoding == report.decode_calls
-    # the first token of each prompt is the prefill's own sync
-    firsts = [s for s in _named(spans, "engine.sync")
-              if s.parent.name == "engine.prefill"]
-    assert len(firsts) == report.completed
+    # a prefill never waits: its first token is read with the next sync
+    assert all(s.parent.name == "engine.iter"
+               for s in _named(spans, "engine.sync"))
 
 
 def test_spans_of_a_request_carry_its_rid(traced):
