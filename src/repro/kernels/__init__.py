@@ -5,7 +5,10 @@
 - ``nm_spmm_gather``: Tier-2 lane-aligned reduced-K SPMM (beyond paper)
 - ``flash_attention``: chunked online-softmax attention
 
-All validated against ``ref.py`` oracles in interpret mode (CPU); on-TPU
+Each GEMM family builds one ``pallas_call`` on the grid of ``gemm``;
+its forms (float or quantized, single or fused gate-up, raw
+accumulator, activation block skip, epilogue) are arguments.  All are
+validated against ``ref.py`` oracles in interpret mode (CPU); on-TPU
 execution uses the same ``pallas_call`` with ``interpret=False``.
 
 The kernels are not called directly by models: the **dispatch engine**

@@ -62,6 +62,8 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
+import importlib
 import logging
 import math
 import types
@@ -393,6 +395,12 @@ _BKE_CAPS = (1024, 512, 256, 128)
 # Mosaic tiles the second-to-last (sublane) dim of a block in 8-row
 # quanta unless the block spans the whole array dim
 _SUBLANE = 8
+# the narrow dtypes (int8, fp8) pack 4x more values per 32-bit lane
+# register than fp32, so the sublane quantum of a quantized operand tile
+# is 32 rows — quantized contraction blocks must be multiples of 32, and
+# the float entries decline quantized problems outright (casting would
+# break the storage model).
+_Q_SUBLANE = 32
 
 
 def _fit_rows(b: int, cap: int) -> Optional[int]:
@@ -419,31 +427,93 @@ def _enumerate(b, ke, o, ke_multiple):
     return out
 
 
-def _is_int8(dtype) -> bool:
-    return jnp.dtype(dtype) == jnp.int8
+def _rows_multiple(n: int, rows: int) -> int:
+    """K multiple that makes a K block's ``block_k * n / 4`` compressed
+    rows a multiple of ``rows``."""
+    return 4 * rows // math.gcd(n, 4 * rows)
 
 
-def _is_fp8(dtype) -> bool:
-    return jnp.dtype(dtype) == jnp.float8_e4m3fn
+@dataclasses.dataclass(frozen=True)
+class _Family:
+    """One GEMM family: its kernel (``repro.kernels.<kernel>.kernel``,
+    taking the weight leaves, then ``n`` for the N:M families, which fix
+    M=4), the leaves it reads (the first is the operand the dtype class
+    describes), and the K multiple its blocks need for ``(n, narrow)``."""
+
+    kernel: str
+    leaves: Tuple[str, ...]
+    k_multiple: Callable[[int, bool], int]
 
 
-# the narrow dtypes (int8, fp8) pack 4x more values per 32-bit lane
-# register than fp32, so the sublane quantum of a quantized operand tile
-# is 32 rows (vs 8 for fp32) — quantized contraction blocks must be
-# multiples of 32, and the float entries decline quantized problems
-# outright (casting would break the storage model).
-_Q_SUBLANE = 32
+_FAMILIES = {
+    # narrow weight tiles sit on the 32-row quantum; float ones take any
+    # divisor
+    "dense": _Family("tile_gemm", ("w",),
+                     lambda n, narrow: _Q_SUBLANE if narrow else 1),
+    # meta packs 4 compressed rows a byte
+    "compressed": _Family("nm_spmm", ("values", "meta_packed"),
+                          lambda n, narrow: _rows_multiple(
+                              n, _Q_SUBLANE if narrow else 4)),
+    # the gather reads x in 4-row blocks
+    "gather": _Family("nm_spmm_gather", ("values", "gather_idx"),
+                      lambda n, narrow: _rows_multiple(n, _Q_SUBLANE)
+                      if narrow else 4),
+}
 
 
-def _fit_tile_gemm(b, ke, o, n, m, dtype):
-    if quant.is_quantized_dtype(dtype):
+@dataclasses.dataclass(frozen=True)
+class _DtypeClass:
+    """One execution class of the dtype axis: the storage dtype its
+    entries accept (``None``: any float), the row quantum of their
+    activation tiles, and the backend gate.  Quantized classes quantize
+    activations per row on the way in and pad them to the quantum; the
+    adapter slices the output back."""
+
+    storage: Any
+    rows: int
+    supported: Optional[Callable[[str], bool]] = None
+
+    @property
+    def quantized(self) -> bool:
+        return self.storage is not None
+
+    def padded(self, b: int) -> int:
+        """Rows of the activation tile after final-block padding: a
+        quantized class zero-pads odd row counts (e.g. a decode batch of
+        3) up to its quantum rather than declining them."""
+        return b + (-b) % self.rows if self.quantized else b
+
+
+_DTYPE_CLASSES = {
+    "": _DtypeClass(None, _SUBLANE),
+    "_int8": _DtypeClass(jnp.dtype(jnp.int8), _Q_SUBLANE),
+    "_fp8": _DtypeClass(jnp.dtype(jnp.float8_e4m3fn), _Q_SUBLANE,
+                        registry.supports_fp8),
+}
+
+
+def _fit(mode, dc, b, ke, o, n, m, dtype):
+    if (jnp.dtype(dtype) != dc.storage if dc.quantized
+            else quant.is_quantized_dtype(dtype)):
         return None
-    bb = _fit_rows(b, 128)
+    if mode != "dense" and m != 4:
+        return None
+    bb = (largest_fitting_block(dc.padded(b), 128, dc.rows) if dc.quantized
+          else _fit_rows(b, 128))
     bo = largest_fitting_block(o, 128)
-    bke = largest_fitting_block(ke, 512)
+    bke = largest_fitting_block(ke, 512,
+                                _FAMILIES[mode].k_multiple(n, dc.quantized))
     if bb is None or bo is None or bke is None:
         return None
     return (bb, bke, bo)
+
+
+def _candidates(mode, dc, b, ke, o, n, m, dtype):
+    cands = _enumerate(dc.padded(b), ke, o,
+                       _FAMILIES[mode].k_multiple(n, dc.quantized))
+    if dc.quantized:
+        return [c for c in cands if c[0] % dc.rows == 0] or cands
+    return cands
 
 
 def _epi_kwargs(epilogue: Optional[Epilogue]) -> Dict[str, Any]:
@@ -455,202 +525,6 @@ def _epi_kwargs(epilogue: Optional[Epilogue]) -> Dict[str, Any]:
         return {}
     return {"epilogue": epilogue.spec, "bias": epilogue.bias,
             "requant_scale": epilogue.requant_scale}
-
-
-def _run_tile_gemm(x2, params, cfg, g, blocks, interpret, out_dtype,
-                   epilogue=None, activation=None):
-    from repro.kernels.tile_gemm.kernel import tile_gemm, tile_gemm_masked
-
-    bb, bke, bo = blocks
-    w = g(params["w"]).astype(x2.dtype)
-    if activation is not None:
-        # x2 is already masked (sparse_matmul applies the mask pass on
-        # every route); the skip maps only elide dead-block work
-        kmap, kmask = block_maps(x2, bb, bke)
-        return tile_gemm_masked(x2, w, kmap, kmask,
-                                block_b=bb, block_k=bke, block_o=bo,
-                                out_dtype=out_dtype, interpret=interpret,
-                                **_epi_kwargs(epilogue))
-    return tile_gemm(x2, w, block_b=bb, block_k=bke, block_o=bo,
-                     out_dtype=out_dtype, interpret=interpret,
-                     **_epi_kwargs(epilogue))
-
-
-def _nm_ke_multiple(n: int) -> int:
-    # nm_spmm packs meta 4 rows/byte: block_kc = block_ke*n/4 must be a
-    # positive multiple of 4 -> block_ke*n % 16 == 0.
-    return 16 // math.gcd(n, 16)
-
-
-def _fit_nm_spmm(b, ke, o, n, m, dtype):
-    if m != 4 or quant.is_quantized_dtype(dtype):
-        return None  # kernel fixes M=4 (paper's detailed design)
-    bb = _fit_rows(b, 128)
-    bo = largest_fitting_block(o, 128)
-    bke = largest_fitting_block(ke, 512, _nm_ke_multiple(n))
-    if bb is None or bo is None or bke is None:
-        return None
-    return (bb, bke, bo)
-
-
-def _run_nm_spmm(x2, params, cfg, g, blocks, interpret, out_dtype,
-                 epilogue=None, activation=None):
-    from repro.kernels.nm_spmm.kernel import nm_spmm, nm_spmm_masked
-
-    bb, bke, bo = blocks
-    v = g(params["values"]).astype(x2.dtype)
-    if activation is not None:
-        kmap, kmask = block_maps(x2, bb, bke)
-        return nm_spmm_masked(x2, v, params["meta_packed"], kmap, kmask,
-                              cfg.n, block_b=bb, block_o=bo, block_ke=bke,
-                              out_dtype=out_dtype, interpret=interpret,
-                              **_epi_kwargs(epilogue))
-    return nm_spmm(x2, v, params["meta_packed"], cfg.n,
-                   block_b=bb, block_o=bo, block_ke=bke,
-                   out_dtype=out_dtype, interpret=interpret,
-                   **_epi_kwargs(epilogue))
-
-
-def _fit_nm_gather(b, ke, o, n, m, dtype):
-    if m != 4 or quant.is_quantized_dtype(dtype):
-        return None
-    bb = _fit_rows(b, 128)
-    bo = largest_fitting_block(o, 128)
-    # kernel reshapes the activation tile into 4-row blocks: block_ke % 4 == 0
-    bke = largest_fitting_block(ke, 512, 4)
-    if bb is None or bo is None or bke is None:
-        return None
-    return (bb, bke, bo)
-
-
-def _run_nm_gather(x2, params, cfg, g, blocks, interpret, out_dtype,
-                   epilogue=None, activation=None):
-    from repro.kernels.nm_spmm_gather.kernel import (
-        nm_spmm_gather_bk, nm_spmm_gather_bk_masked)
-
-    bb, bke, bo = blocks
-    v = g(params["values"]).astype(x2.dtype)
-    idx = params["gather_idx"].reshape(-1, 1)
-    # bk layout: natural (B, K_eff) in / (B, O) out — the row gather and
-    # both transposes live in the kernel's index map, so no permuted
-    # activation copy is ever materialized in HBM
-    if activation is not None:
-        kmap, kmask = block_maps(x2, bb, bke)
-        return nm_spmm_gather_bk_masked(
-            x2, v, idx, kmap, kmask, cfg.n,
-            block_b=bb, block_o=bo, block_ke=bke,
-            out_dtype=out_dtype, interpret=interpret,
-            **_epi_kwargs(epilogue))
-    return nm_spmm_gather_bk(x2, v, idx, cfg.n,
-                             block_b=bb, block_o=bo, block_ke=bke,
-                             out_dtype=out_dtype, interpret=interpret,
-                             **_epi_kwargs(epilogue))
-
-
-# --- fused gate-up (dual) adapters: ONE pallas_call reads the
-# activation tile once, contracts it against BOTH same-shaped weights,
-# and emits silu(g) * u (the "silu_mul" epilogue point) directly.
-# Registered as ``run_dual`` on the same entries; plans with
-# ``dual=True`` only fuse when the selected entry carries one.
-
-def _dual_epi_kwargs(epilogue: Optional[Epilogue]) -> Dict[str, Any]:
-    # the dual kernels default to the bare silu_mul point; only a
-    # requant extension needs operands (bias is unsupported on duals)
-    if epilogue is None:
-        return {}
-    return {"epilogue": epilogue.spec,
-            "requant_scale": epilogue.requant_scale}
-
-
-def _run_tile_gemm_dual(x2, pg, pu, cfg, g, blocks, interpret, out_dtype,
-                        epilogue=None):
-    from repro.kernels.tile_gemm.kernel import tile_gemm_dual
-
-    bb, bke, bo = blocks
-    return tile_gemm_dual(x2, g(pg["w"]).astype(x2.dtype),
-                          g(pu["w"]).astype(x2.dtype),
-                          block_b=bb, block_k=bke, block_o=bo,
-                          out_dtype=out_dtype, interpret=interpret,
-                          **_dual_epi_kwargs(epilogue))
-
-
-def _run_nm_spmm_dual(x2, pg, pu, cfg, g, blocks, interpret, out_dtype,
-                      epilogue=None):
-    from repro.kernels.nm_spmm.kernel import nm_spmm_dual
-
-    bb, bke, bo = blocks
-    return nm_spmm_dual(x2, g(pg["values"]).astype(x2.dtype),
-                        pg["meta_packed"],
-                        g(pu["values"]).astype(x2.dtype),
-                        pu["meta_packed"], cfg.n,
-                        block_b=bb, block_o=bo, block_ke=bke,
-                        out_dtype=out_dtype, interpret=interpret,
-                        **_dual_epi_kwargs(epilogue))
-
-
-def _run_nm_gather_dual(x2, pg, pu, cfg, g, blocks, interpret, out_dtype,
-                        epilogue=None):
-    from repro.kernels.nm_spmm_gather.kernel import nm_spmm_gather_dual_bk
-
-    bb, bke, bo = blocks
-    return nm_spmm_gather_dual_bk(
-        x2, g(pg["values"]).astype(x2.dtype),
-        pg["gather_idx"].reshape(-1, 1),
-        g(pu["values"]).astype(x2.dtype),
-        pu["gather_idx"].reshape(-1, 1), cfg.n,
-        block_b=bb, block_o=bo, block_ke=bke,
-        out_dtype=out_dtype, interpret=interpret,
-        **_dual_epi_kwargs(epilogue))
-
-
-registry.register(KernelEntry(
-    name="tile_gemm", mode="dense", activation_skip=True,
-    fit_blocks=_fit_tile_gemm, run=_run_tile_gemm,
-    run_dual=_run_tile_gemm_dual,
-    candidates=lambda b, ke, o, n, m, dtype: _enumerate(b, ke, o, 1),
-))
-registry.register(KernelEntry(
-    name="nm_spmm", mode="compressed", activation_skip=True,
-    fit_blocks=_fit_nm_spmm, run=_run_nm_spmm,
-    run_dual=_run_nm_spmm_dual,
-    candidates=lambda b, ke, o, n, m, dtype: _enumerate(
-        b, ke, o, _nm_ke_multiple(n)),
-))
-registry.register(KernelEntry(
-    name="nm_spmm_gather", mode="gather", activation_skip=True,
-    fit_blocks=_fit_nm_gather, run=_run_nm_gather,
-    run_dual=_run_nm_gather_dual,
-    candidates=lambda b, ke, o, n, m, dtype: _enumerate(b, ke, o, 4),
-))
-
-
-# --- quantized entries (int8 VNNI lineage + fp8 e4m3fn): narrow values
-# x narrow row-quantized activations contracted into the wide
-# accumulator (int32 / fp32), dequantized once on the way out.
-# Registered at higher priority; their fit_blocks only accept problems
-# of their own storage dtype, so float dispatch is untouched and the
-# two quantized classes never collide.
-
-def _q_ke_multiple(n: int) -> int:
-    # the compressed values tile (block_kc = block_ke*n/4 rows) must hit
-    # the 32-row narrow-dtype sublane quantum: block_ke*n % 128 == 0.
-    # This also covers meta packing (block_ke*n % 16) and the
-    # dense/gather cases.
-    return (4 * _Q_SUBLANE) // math.gcd(n, 4 * _Q_SUBLANE)
-
-
-def _q_padded_b(b: int) -> int:
-    """Row count of the quantized activation tile after final-block
-    padding.
-
-    The quantized activation operand is narrow too, so its sublane (row)
-    axis carries the same 32-row quantum as the values tile.  Rather than
-    rejecting row counts off the quantum — which would throw every odd
-    decode batch (e.g. b=3) back to the dequantize reference — the run
-    adapters zero-pad the final row block up to the quantum and slice the
-    output back; blocks are fitted against the padded row count.
-    """
-    return b + (-b) % _Q_SUBLANE
 
 
 def _quantize_acts(x2, params, dtype):
@@ -690,306 +564,68 @@ def _pad_rows(xq, xs, b_pad: int):
     return xq, xs
 
 
-def _fit_q_rows(b: int):
-    return largest_fitting_block(_q_padded_b(b), 128, _Q_SUBLANE)
+def _run(mode, dc, x2, params, cfg, g, blocks, interpret, out_dtype,
+         epilogue=None, activation=None, up=None, raw=False):
+    """Run ``mode``'s kernel in dtype class ``dc`` on ``x2 (B, K)``.
 
-
-def _fit_dense_q(b, ke, o):
-    bb = _fit_q_rows(b)
-    bo = largest_fitting_block(o, 128)
-    bke = largest_fitting_block(ke, 512, _Q_SUBLANE)
-    if bb is None or bo is None or bke is None:
-        return None
-    return (bb, bke, bo)
-
-
-def _fit_nm_q(b, ke, o, n):
-    bb = _fit_q_rows(b)
-    bo = largest_fitting_block(o, 128)
-    bke = largest_fitting_block(ke, 512, _q_ke_multiple(n))
-    if bb is None or bo is None or bke is None:
-        return None
-    return (bb, bke, bo)
-
-
-def _fit_tile_gemm_int8(b, ke, o, n, m, dtype):
-    return _fit_dense_q(b, ke, o) if _is_int8(dtype) else None
-
-
-def _fit_tile_gemm_fp8(b, ke, o, n, m, dtype):
-    return _fit_dense_q(b, ke, o) if _is_fp8(dtype) else None
-
-
-def _fit_nm_spmm_int8(b, ke, o, n, m, dtype):
-    if m != 4 or not _is_int8(dtype):
-        return None
-    return _fit_nm_q(b, ke, o, n)
-
-
-def _fit_nm_spmm_fp8(b, ke, o, n, m, dtype):
-    if m != 4 or not _is_fp8(dtype):
-        return None
-    return _fit_nm_q(b, ke, o, n)
-
-
-def _fit_nm_gather_int8(b, ke, o, n, m, dtype):
-    if m != 4 or not _is_int8(dtype):
-        return None
-    return _fit_nm_q(b, ke, o, n)
-
-
-def _fit_nm_gather_fp8(b, ke, o, n, m, dtype):
-    if m != 4 or not _is_fp8(dtype):
-        return None
-    return _fit_nm_q(b, ke, o, n)
-
-
-def _dense_q_kernel(dtype):
-    from repro.kernels.tile_gemm.kernel import tile_gemm_fp8, tile_gemm_int8
-
-    return tile_gemm_fp8 if _is_fp8(dtype) else tile_gemm_int8
-
-
-def _nm_q_kernel(dtype):
-    from repro.kernels.nm_spmm.kernel import nm_spmm_fp8, nm_spmm_int8
-
-    return nm_spmm_fp8 if _is_fp8(dtype) else nm_spmm_int8
-
-
-def _gather_q_kernel(dtype):
-    from repro.kernels.nm_spmm_gather.kernel import (nm_spmm_gather_fp8,
-                                                     nm_spmm_gather_int8)
-
-    return nm_spmm_gather_fp8 if _is_fp8(dtype) else nm_spmm_gather_int8
-
-
-def _run_tile_gemm_q(x2, params, cfg, g, blocks, interpret, out_dtype,
-                     epilogue=None, activation=None):
+    ``up`` is the up projection's leaf of a fused gate-up pair (``params``
+    the gate's): one pallas_call reads each x tile once and emits
+    ``silu(g) * u``.  ``activation`` (a plan that granted the skip) runs
+    the block-skip form.  ``raw`` (quantized classes): ``x2`` arrives
+    already quantized and padded, and the undequantized accumulator comes
+    back, for a psum before the one dequantize.  A quantized class
+    otherwise quantizes ``x2`` once — a gate-up pair with the gate
+    leaf's scale, both sites being calibrated on the same tensor — pads
+    it to the row quantum and slices the output back.
+    """
+    fam = _FAMILIES[mode]
+    kernel = getattr(importlib.import_module(
+        f"repro.kernels.{fam.kernel}.kernel"), fam.kernel)
     bb, bke, bo = blocks
     b = x2.shape[0]
-    qdt = params["w"].dtype
-    xq, xs = _pad_rows(*_quantize_acts(x2, params, qdt), _q_padded_b(b))
-    ws = params[quant.SCALE_KEY].reshape(1, -1)
+    scaled = dc.quantized and not raw
+    xk, xs = (_pad_rows(*_quantize_acts(x2, params, dc.storage),
+                        dc.padded(b)) if scaled else (x2, None))
+
+    def leaves(p):
+        first, *rest = fam.leaves
+        return (g(p[first]).astype(xk.dtype), *(p[k] for k in rest))
+
+    kw = dict(block_b=bb, block_k=bke, block_o=bo, interpret=interpret)
+    w = leaves(params)
+    if up is not None:
+        kw["up"] = leaves(up)
+    if raw:
+        kw["raw"] = True
+    else:
+        kw.update(out_dtype=out_dtype, **_epi_kwargs(epilogue))
+    if scaled:
+        kw["x_scale"] = xs
+        kw["w_scale"] = params[quant.SCALE_KEY].reshape(1, -1)
+        if up is not None:
+            kw["up_scale"] = up[quant.SCALE_KEY].reshape(1, -1)
     if activation is not None:
-        from repro.kernels.tile_gemm.kernel import tile_gemm_masked
-
-        # maps come from the PADDED narrow rows: zeros quantize to zero
-        # (and padding rows ARE zero), so dead blocks stay detectable
-        kmap, kmask = block_maps(xq, bb, bke)
-        y = tile_gemm_masked(xq, g(params["w"]), kmap, kmask, xs, ws,
-                             acc_dtype=_dual_q_acc(qdt),
-                             block_b=bb, block_k=bke, block_o=bo,
-                             out_dtype=out_dtype, interpret=interpret,
-                             **_epi_kwargs(epilogue))
-        return y[:b]
-    y = _dense_q_kernel(qdt)(xq, g(params["w"]), xs, ws,
-                             block_b=bb, block_k=bke, block_o=bo,
-                             out_dtype=out_dtype, interpret=interpret,
-                             **_epi_kwargs(epilogue))
-    return y[:b]
+        # x2 is already masked (sparse_matmul applies the mask pass on
+        # every route); the maps come from the operand the kernel
+        # contracts (zeros quantize to zero and padding rows are zero),
+        # and only elide dead-block work
+        kw["kmap"], kw["kmask"] = block_maps(xk, bb, bke)
+    y = kernel(xk, *w, *(() if mode == "dense" else (cfg.n,)), **kw)
+    return y[:b] if scaled else y
 
 
-def _partial_tile_gemm_q(xq, params, cfg, blocks, interpret):
-    bb, bke, bo = blocks
-    return _dense_q_kernel(params["w"].dtype)(
-        xq, params["w"], block_b=bb, block_k=bke, block_o=bo,
-        interpret=interpret)
-
-
-def _run_nm_spmm_q(x2, params, cfg, g, blocks, interpret, out_dtype,
-                   epilogue=None, activation=None):
-    bb, bke, bo = blocks
-    b = x2.shape[0]
-    qdt = params["values"].dtype
-    xq, xs = _pad_rows(*_quantize_acts(x2, params, qdt), _q_padded_b(b))
-    ws = params[quant.SCALE_KEY].reshape(1, -1)
-    if activation is not None:
-        from repro.kernels.nm_spmm.kernel import nm_spmm_masked
-
-        kmap, kmask = block_maps(xq, bb, bke)
-        y = nm_spmm_masked(xq, g(params["values"]), params["meta_packed"],
-                           kmap, kmask, cfg.n, xs, ws,
-                           acc_dtype=_dual_q_acc(qdt),
-                           block_b=bb, block_o=bo, block_ke=bke,
-                           out_dtype=out_dtype, interpret=interpret,
-                           **_epi_kwargs(epilogue))
-        return y[:b]
-    y = _nm_q_kernel(qdt)(xq, g(params["values"]), params["meta_packed"],
-                          xs, ws, cfg.n,
-                          block_b=bb, block_o=bo, block_ke=bke,
-                          out_dtype=out_dtype, interpret=interpret,
-                          **_epi_kwargs(epilogue))
-    return y[:b]
-
-
-def _partial_nm_spmm_q(xq, params, cfg, blocks, interpret):
-    bb, bke, bo = blocks
-    return _nm_q_kernel(params["values"].dtype)(
-        xq, params["values"], params["meta_packed"], None, None, cfg.n,
-        block_b=bb, block_o=bo, block_ke=bke, interpret=interpret)
-
-
-def _run_nm_gather_q(x2, params, cfg, g, blocks, interpret, out_dtype,
-                     epilogue=None, activation=None):
-    from repro.kernels.nm_spmm_gather.kernel import (
-        nm_spmm_gather_bk, nm_spmm_gather_bk_masked)
-
-    bb, bke, bo = blocks
-    b = x2.shape[0]
-    qdt = params["values"].dtype
-    xq, xs = _pad_rows(*_quantize_acts(x2, params, qdt), _q_padded_b(b))
-    ws = params[quant.SCALE_KEY].reshape(1, -1)
-    idx = params["gather_idx"].reshape(-1, 1)
-    # bk layout (see _run_nm_gather): no xq.T / y_t.T HBM round trips
-    if activation is not None:
-        kmap, kmask = block_maps(xq, bb, bke)
-        y = nm_spmm_gather_bk_masked(
-            xq, g(params["values"]), idx, kmap, kmask, cfg.n, xs, ws,
-            acc_dtype=jnp.int32 if _is_int8(qdt) else jnp.float32,
-            block_b=bb, block_o=bo, block_ke=bke,
-            out_dtype=out_dtype, interpret=interpret,
-            **_epi_kwargs(epilogue))
-        return y[:b]
-    y = nm_spmm_gather_bk(xq, g(params["values"]), idx, cfg.n, xs, ws,
-                          acc_dtype=jnp.int32 if _is_int8(qdt)
-                          else jnp.float32,
-                          block_b=bb, block_o=bo, block_ke=bke,
-                          out_dtype=out_dtype, interpret=interpret,
-                          **_epi_kwargs(epilogue))
-    return y[:b]
-
-
-def _partial_nm_gather_q(xq, params, cfg, blocks, interpret):
-    bb, bke, bo = blocks
-    idx = params["gather_idx"].reshape(-1, 1)
-    y_t = _gather_q_kernel(params["values"].dtype)(
-        xq.T, params["values"], idx, None, None, cfg.n,
-        block_b=bb, block_o=bo, block_ke=bke, interpret=interpret)
-    return y_t.T
-
-
-# --- fused gate-up (dual) quantized adapters (see the float duals
-# above the float registrations): one x read, one quantize pass.
-
-def _dual_q_acc(qdt):
-    return jnp.int32 if _is_int8(qdt) else jnp.float32
-
-
-def _run_tile_gemm_dual_q(x2, pg, pu, cfg, g, blocks, interpret, out_dtype,
-                          epilogue=None):
-    from repro.kernels.tile_gemm.kernel import tile_gemm_dual
-
-    bb, bke, bo = blocks
-    b = x2.shape[0]
-    qdt = pg["w"].dtype
-    # one x read, one quantize pass: the gate leaf's scale quantizes the
-    # shared activations (both sites calibrated on the same tensor)
-    xq, xs = _pad_rows(*_quantize_acts(x2, pg, qdt), _q_padded_b(b))
-    y = tile_gemm_dual(xq, g(pg["w"]), g(pu["w"]), xs,
-                       pg[quant.SCALE_KEY].reshape(1, -1),
-                       pu[quant.SCALE_KEY].reshape(1, -1),
-                       acc_dtype=_dual_q_acc(qdt),
-                       block_b=bb, block_k=bke, block_o=bo,
-                       out_dtype=out_dtype, interpret=interpret,
-                       **_dual_epi_kwargs(epilogue))
-    return y[:b]
-
-
-def _run_nm_spmm_dual_q(x2, pg, pu, cfg, g, blocks, interpret, out_dtype,
-                        epilogue=None):
-    from repro.kernels.nm_spmm.kernel import nm_spmm_dual
-
-    bb, bke, bo = blocks
-    b = x2.shape[0]
-    qdt = pg["values"].dtype
-    xq, xs = _pad_rows(*_quantize_acts(x2, pg, qdt), _q_padded_b(b))
-    y = nm_spmm_dual(xq, g(pg["values"]), pg["meta_packed"],
-                     g(pu["values"]), pu["meta_packed"], cfg.n, xs,
-                     pg[quant.SCALE_KEY].reshape(1, -1),
-                     pu[quant.SCALE_KEY].reshape(1, -1),
-                     acc_dtype=_dual_q_acc(qdt),
-                     block_b=bb, block_o=bo, block_ke=bke,
-                     out_dtype=out_dtype, interpret=interpret,
-                     **_dual_epi_kwargs(epilogue))
-    return y[:b]
-
-
-def _run_nm_gather_dual_q(x2, pg, pu, cfg, g, blocks, interpret, out_dtype,
-                          epilogue=None):
-    from repro.kernels.nm_spmm_gather.kernel import nm_spmm_gather_dual_bk
-
-    bb, bke, bo = blocks
-    b = x2.shape[0]
-    qdt = pg["values"].dtype
-    xq, xs = _pad_rows(*_quantize_acts(x2, pg, qdt), _q_padded_b(b))
-    y = nm_spmm_gather_dual_bk(
-        xq, g(pg["values"]), pg["gather_idx"].reshape(-1, 1),
-        g(pu["values"]), pu["gather_idx"].reshape(-1, 1), cfg.n, xs,
-        pg[quant.SCALE_KEY].reshape(1, -1),
-        pu[quant.SCALE_KEY].reshape(1, -1),
-        acc_dtype=_dual_q_acc(qdt),
-        block_b=bb, block_o=bo, block_ke=bke,
-        out_dtype=out_dtype, interpret=interpret,
-        **_dual_epi_kwargs(epilogue))
-    return y[:b]
-
-
-def _q_candidates(b, ke, o, ke_multiple):
-    cands = _enumerate(_q_padded_b(b), ke, o, ke_multiple)
-    return [c for c in cands if c[0] % _Q_SUBLANE == 0] or cands
-
-
-registry.register(KernelEntry(
-    name="tile_gemm_int8", mode="dense", priority=10, activation_skip=True,
-    fit_blocks=_fit_tile_gemm_int8, run=_run_tile_gemm_q,
-    run_dual=_run_tile_gemm_dual_q,
-    quantized=True, run_quantized=_partial_tile_gemm_q,
-    candidates=lambda b, ke, o, n, m, dtype: _q_candidates(
-        b, ke, o, _Q_SUBLANE),
-))
-registry.register(KernelEntry(
-    name="nm_spmm_int8", mode="compressed", priority=10, activation_skip=True,
-    fit_blocks=_fit_nm_spmm_int8, run=_run_nm_spmm_q,
-    run_dual=_run_nm_spmm_dual_q,
-    quantized=True, run_quantized=_partial_nm_spmm_q,
-    candidates=lambda b, ke, o, n, m, dtype: _q_candidates(
-        b, ke, o, _q_ke_multiple(n)),
-))
-registry.register(KernelEntry(
-    name="nm_spmm_gather_int8", mode="gather", priority=10, activation_skip=True,
-    fit_blocks=_fit_nm_gather_int8, run=_run_nm_gather_q,
-    run_dual=_run_nm_gather_dual_q,
-    quantized=True, run_quantized=_partial_nm_gather_q,
-    candidates=lambda b, ke, o, n, m, dtype: _q_candidates(
-        b, ke, o, _q_ke_multiple(n)),
-))
-registry.register(KernelEntry(
-    name="tile_gemm_fp8", mode="dense", priority=10, activation_skip=True,
-    fit_blocks=_fit_tile_gemm_fp8, run=_run_tile_gemm_q,
-    run_dual=_run_tile_gemm_dual_q,
-    quantized=True, run_quantized=_partial_tile_gemm_q,
-    supported=registry.supports_fp8,
-    candidates=lambda b, ke, o, n, m, dtype: _q_candidates(
-        b, ke, o, _Q_SUBLANE),
-))
-registry.register(KernelEntry(
-    name="nm_spmm_fp8", mode="compressed", priority=10, activation_skip=True,
-    fit_blocks=_fit_nm_spmm_fp8, run=_run_nm_spmm_q,
-    run_dual=_run_nm_spmm_dual_q,
-    quantized=True, run_quantized=_partial_nm_spmm_q,
-    supported=registry.supports_fp8,
-    candidates=lambda b, ke, o, n, m, dtype: _q_candidates(
-        b, ke, o, _q_ke_multiple(n)),
-))
-registry.register(KernelEntry(
-    name="nm_spmm_gather_fp8", mode="gather", priority=10, activation_skip=True,
-    fit_blocks=_fit_nm_gather_fp8, run=_run_nm_gather_q,
-    run_dual=_run_nm_gather_dual_q,
-    quantized=True, run_quantized=_partial_nm_gather_q,
-    supported=registry.supports_fp8,
-    candidates=lambda b, ke, o, n, m, dtype: _q_candidates(
-        b, ke, o, _q_ke_multiple(n)),
-))
+# one entry per (dtype class, family): the quantized classes at higher
+# priority, and each accepts only its own storage dtype, so float
+# dispatch is untouched and the two quantized classes never collide
+for _suffix, _dc in _DTYPE_CLASSES.items():
+    for _mode, _fam in _FAMILIES.items():
+        registry.register(KernelEntry(
+            name=_fam.kernel + _suffix, mode=_mode,
+            priority=10 if _dc.quantized else 0, quantized=_dc.quantized,
+            supported=_dc.supported,
+            fit_blocks=functools.partial(_fit, _mode, _dc),
+            run=functools.partial(_run, _mode, _dc),
+            candidates=functools.partial(_candidates, _mode, _dc)))
 
 
 # --- flash attention: mode "attention", dims mapped as (b, ke, o) =
@@ -1173,17 +809,15 @@ def plan(
     the reference formulation — so every other route reports ``[jnp]``
     and the caller applies ``apply_reference``.  ``dual`` marks a fused
     gate-up (two same-shaped weights, one activation read); it
-    additionally requires the selected entry to carry a ``run_dual``
-    kernel.
+    is fused like any other point.
 
     ``activation`` is the dynamic activation-sparsity point
     (``ActivationSpec.point``).  The mask pass is applied to ``x`` on
     every route (it is the semantics of the execution class), so the
     decision only reports whether the selected kernel additionally
     *skips* dead K-blocks in-kernel (``activation_skip``) — which needs
-    a single-placement, non-dual decision on an entry whose adapter
-    carries a masked variant.  Declining the skip never changes
-    numerics.
+    a single-placement, non-dual decision.  Declining the skip never
+    changes numerics.
     """
     if isinstance(problem, str):
         quant.warn_deprecated_once(
@@ -1248,28 +882,20 @@ def plan(
     acts = (("static" if p.static_scales else "dynamic")
             if entry.quantized else None)
     # epilogue fusion: single placement only (shard_map bodies psum
-    # BEFORE the epilogue may run); dual plans additionally need an
-    # entry carrying a run_dual kernel
+    # BEFORE the epilogue may run)
     epi_code = None
     if p.epilogue is not None:
-        if placement != "single":
-            epi_code = ReasonCode.EPILOGUE_SHARDED
-        elif p.dual and entry.run_dual is None:
-            epi_code = ReasonCode.EPILOGUE_NO_DUAL_KERNEL
-        else:
-            epi_code = ReasonCode.EPILOGUE_FUSED
+        epi_code = (ReasonCode.EPILOGUE_FUSED if placement == "single"
+                    else ReasonCode.EPILOGUE_SHARDED)
     fused = epi_code is ReasonCode.EPILOGUE_FUSED
     # in-kernel dead-block skip: single placement only (shard_map bodies
-    # would need per-shard maps), never on duals (no masked dual
-    # kernels), and only on entries whose adapter carries the variant
+    # would need per-shard maps), never on duals (no masked dual form)
     act_code = None
     if p.activation is not None:
         if placement != "single":
             act_code = ReasonCode.ACT_MASK_ONLY_SHARDED
         elif p.dual:
             act_code = ReasonCode.ACT_MASK_ONLY_DUAL
-        elif not entry.activation_skip:
-            act_code = ReasonCode.ACT_MASK_ONLY_ENTRY
         else:
             act_code = ReasonCode.ACT_SKIP
     skip = act_code is ReasonCode.ACT_SKIP
@@ -1620,7 +1246,7 @@ def _shard_map_runner(
     p_specs = _shard_param_specs(mode, shard, params)
     out_spec = P(shard.batch, shard.o)
     needs_psum = shard.collective == "psum"
-    quantized_psum = needs_psum and entry.run_quantized is not None
+    quantized_psum = needs_psum and entry.quantized
     qdt = quant.quant_dtype(params)
 
     def body(x_l, params_l):
@@ -1636,8 +1262,9 @@ def _shard_map_runner(
                                  axis=-1, keepdims=True)
                 xq, xs = quant.quantize_rows(
                     x_l, absmax=jax.lax.pmax(absmax, shard.ke), dtype=qdt)
-            xq_p, _ = _pad_rows(xq, xs, _q_padded_b(b_l))
-            acc = entry.run_quantized(xq_p, params_l, cfg, blocks, interpret)
+            xq_p, _ = _pad_rows(xq, xs, b_l + (-b_l) % _Q_SUBLANE)
+            acc = entry.run(xq_p, params_l, cfg, lambda w: w, blocks,
+                            interpret, None, raw=True)
             acc = jax.lax.psum(acc, shard.ke)
             ws = params_l[quant.SCALE_KEY].reshape(1, -1)
             y = acc[:b_l].astype(jnp.float32) * xs * ws
@@ -1689,9 +1316,9 @@ def sparse_matmul(
     ``activation`` opts this call into the dynamic activation-sparsity
     execution class: the induced mask is applied to ``x`` up front on
     EVERY route (identity for kind ``"zeros"``), and when the plan lands
-    on a single-placement kernel whose adapter carries a masked variant,
-    dead (row-block, K-block) tiles are additionally skipped in-kernel —
-    loads elided, dots never issued — with bit-identical output.
+    on a single-placement, non-dual kernel, dead (row-block, K-block)
+    tiles are additionally skipped in-kernel — loads elided, dots never
+    issued — with bit-identical output.
 
     ``local=True`` says this call already runs INSIDE a shard_map body
     (e.g. MoE expert linears): planning must not consult the mesh env,
@@ -1795,8 +1422,8 @@ def sparse_matmul(
         return y2.reshape(*lead, o)
 
     fused_epi = epilogue if decision.epilogue_fused else None
-    # the masked (block-skip) variant only runs when the plan granted it
-    # — the adapter then derives the skip maps from the operand it
+    # the block-skip form only runs when the plan granted it — the
+    # adapter then derives the skip maps from the operand it
     # actually contracts (padded narrow rows for the quantized entries)
     act_kw = ({"activation": activation}
               if decision.activation_skip else {})
@@ -1888,39 +1515,6 @@ def requant_plan(
     return result
 
 
-def _concat_gate_up(pg, pu, mode):
-    """One concatenated-O layout for an eligible gate-up pair, so the
-    UNFUSED fallback still reads the activation once (one GEMM over
-    ``[Wg | Wu]`` instead of two over the same x).  ``None`` when the
-    leaves cannot concat — gather keeps per-site index streams, and
-    mismatched aux leaves would change quantization semantics."""
-    if (quant.SCALE_KEY in pg) != (quant.SCALE_KEY in pu):
-        return None
-    if (quant.ACT_SCALE_KEY in pg) != (quant.ACT_SCALE_KEY in pu):
-        return None
-    cat = {}
-    if mode == "dense":
-        cat["w"] = jnp.concatenate([pg["w"], pu["w"]], axis=1)
-    elif mode == "compressed":
-        if pg["meta_packed"].shape != pu["meta_packed"].shape:
-            return None
-        cat["values"] = jnp.concatenate([pg["values"], pu["values"]],
-                                        axis=1)
-        cat["meta_packed"] = jnp.concatenate(
-            [pg["meta_packed"], pu["meta_packed"]], axis=1)
-    else:
-        return None
-    if quant.SCALE_KEY in pg:
-        cat[quant.SCALE_KEY] = jnp.concatenate(
-            [pg[quant.SCALE_KEY].reshape(-1),
-             pu[quant.SCALE_KEY].reshape(-1)], axis=0)
-    if quant.ACT_SCALE_KEY in pg:
-        # both sites calibrated on the SAME tensor, so their scales
-        # agree; the gate leaf's scalar quantizes the shared rows
-        cat[quant.ACT_SCALE_KEY] = pg[quant.ACT_SCALE_KEY]
-    return cat   # note: no _CALIB_KEY — gate_up_matmul records per-site
-
-
 def gate_up_matmul(
     x: jax.Array,
     params_g: Dict[str, Any],
@@ -1947,17 +1541,12 @@ def gate_up_matmul(
     on :func:`sparse_matmul`.
 
     When both leaves share mode/shape/dtype class and the plan lands on
-    a single-placement kernel with a ``run_dual`` variant, ONE
-    pallas_call reads each activation tile once, contracts it against
-    both weights, and emits the epilogue directly.  Otherwise the
-    fallback still reads the activation once where that helps — dense
-    and compressed pairs headed for a (non-dual) kernel concat along O
-    into a single GEMM, while jnp-tier pairs run as two plain GEMMs
-    (a per-call weight concat costs more than a decode-shape GEMM
-    there) — and applies the float silu*mul reference (never the
-    requant: the consumer's own quantize pass is bit-identical on
-    float rows, and the caller sees that in the float dtype of the
-    result).
+    a single-placement kernel, ONE pallas_call reads each activation
+    tile once, contracts it against both weights, and emits the
+    epilogue directly.  Otherwise the pair runs as two GEMMs and the
+    float silu*mul reference (never the requant: the consumer's own
+    quantize pass is bit-identical on float rows, and the caller sees
+    that in the float dtype of the result).
     """
     dcfg = dispatch or _DEFAULT
     g = constrain_fn or (lambda w: w)
@@ -1977,7 +1566,6 @@ def gate_up_matmul(
     ke, o = _problem_dims(mode_g, params_g, x2)
 
     # both sites see the same activations: record each calibration tag
-    # here (the concat fallback cannot carry two tags through one leaf)
     if quant.calibration_active():
         for p in (params_g, params_u):
             if quant._CALIB_KEY in p:
@@ -2013,9 +1601,10 @@ def gate_up_matmul(
         out_dt = jnp.float32 if pre_q else x2.dtype
 
         def run_dual(x2_, pg, pu, rq_scale):
-            return entry.run_dual(
-                x2_, pg, pu, cfg, g, decision.blocks, interpret, out_dt,
-                epilogue=epilib.Epilogue(spec, requant_scale=rq_scale))
+            return entry.run(
+                x2_, pg, cfg, g, decision.blocks, interpret, out_dt,
+                epilogue=epilib.Epilogue(spec, requant_scale=rq_scale),
+                up=pu)
 
         def reference_dual(x2_, pg, pu, rq_scale):
             y_g = _JNP_IMPL[mode_g](x2_, pg, cfg, g)
@@ -2027,30 +1616,14 @@ def gate_up_matmul(
                             epi.requant_scale)
         return y2.reshape(*lead, o)
 
-    # the concat collapse (one GEMM over 2o, activation read once from
-    # HBM) only pays for itself when a kernel actually runs it; on the
-    # jnp tier the per-call O(ke*2o) weight concat costs more than the
-    # decode-shape GEMM it feeds, so two plain XLA GEMMs win there.
-    # Under shard_map both weights are split along o over the model
-    # axis, and [Wg | Wu] split the same way holds other columns than
-    # either: every call would move the weights between chips (an
-    # all-to-all per weight, then collective-permutes to slice the
-    # result apart), so sharded pairs run as two shard_map GEMMs
-    cat = (_concat_gate_up(params_g, params_u, mode_g)
-           if pair_ok and decision is not None and decision.uses_kernel
-           and not decision.uses_shard_map
-           else None)
-    if cat is not None:
-        y2 = sparse_matmul(x2, cat, cfg, constrain_fn=g, dispatch=dcfg,
-                           shard=shard, activation=activation, local=local)
-        y_g, y_u = y2[:, :o], y2[:, o:]
-    else:
-        y_g = sparse_matmul(x2, params_g, cfg, constrain_fn=g,
-                            dispatch=dcfg, shard=shard,
-                            activation=activation, local=local)
-        y_u = sparse_matmul(x2, params_u, cfg, constrain_fn=g,
-                            dispatch=dcfg, shard=shard,
-                            activation=activation, local=local)
+    # every route but a single-placement kernel: two GEMMs.  Under
+    # shard_map both weights are split along o over the model axis, and a
+    # concatenated [Wg | Wu] split the same way would hold other columns
+    # than either, moving the weights between chips on every call
+    y_g = sparse_matmul(x2, params_g, cfg, constrain_fn=g, dispatch=dcfg,
+                        shard=shard, activation=activation, local=local)
+    y_u = sparse_matmul(x2, params_u, cfg, constrain_fn=g, dispatch=dcfg,
+                        shard=shard, activation=activation, local=local)
     h = jax.nn.silu(y_g.astype(jnp.float32)) * y_u.astype(jnp.float32)
     return h.astype(y_g.dtype).reshape(*lead, o)
 

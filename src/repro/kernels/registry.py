@@ -3,11 +3,11 @@
 The paper's point is that ONE engine behind the GEMM ISA serves dense
 (4:4), 2:4, 1:4 and row-wise/unstructured layers.  This module is that
 table on the software side: every Pallas kernel registers a
-:class:`KernelEntry` describing which execution mode it implements, which
-backends it can run on, and — via ``fit_blocks`` — which (shape, N:M,
-dtype) problems it can legally tile.  ``select`` walks the entries in
-priority order and returns the first (entry, blocks) that fits; a ``None``
-result means "no kernel applies, use the jnp reference formulation".
+:class:`KernelEntry` describing which execution mode it implements and
+— via ``fit_blocks`` — which (shape, N:M, dtype) problems it can legally
+tile.  ``select`` walks the entries in priority order and returns the
+first (entry, blocks) that fits; a ``None`` result means "no kernel
+applies, use the jnp reference formulation".
 
 dtype is a real selection axis, not a cast: the int8 (VNNI-lineage) and
 fp8 (e4m3fn) entries fit only problems whose quantized storage dtype
@@ -66,50 +66,33 @@ class KernelEntry:
     default block sizes for the problem, or ``None`` when the kernel's
     shape constraints cannot be met (the registry then falls through).
     ``candidates`` enumerates legal block choices for the autotuner.
-    ``run(x2d, params, n, m, blocks, interpret, out_dtype)`` executes it.
+    ``run(x2d, params, cfg, g, blocks, interpret, out_dtype, ...)``
+    executes it; a GEMM entry's ``run`` also takes ``epilogue``,
+    ``activation`` (the block-skip form), ``up`` (the up projection's
+    leaf: ONE pallas_call contracting the activation tile against both
+    weights and emitting ``silu(g) * u``) and, on quantized entries,
+    ``raw`` (already-quantized activations in, the undequantized
+    accumulator out, so a contraction-sharded problem can psum the raw
+    partials and dequantize once on the gathered result).
 
     ``quantized`` marks the narrow-dtype entries (int8 VNNI lineage and
     fp8) — the engine uses it to annotate activation-scale handling and
-    to route the sharded contraction class.  ``run_quantized(x_q, params,
-    cfg, blocks, interpret) -> (B, O)`` is their raw-accumulator path: it
-    takes ALREADY-quantized activations and returns undequantized partial
-    products in the accumulator dtype (int32 for int8, fp32 for fp8), so
-    a contraction-sharded problem can psum the raw partials and
-    dequantize once on the gathered result.
+    to route the sharded contraction class.
 
     ``supported(backend) -> bool``, when set, vetoes the entry on
     backends whose hardware can't execute it — constraints the
     (shape, dtype) signature handed to ``fit_blocks`` cannot express
     (e.g. the fp8 entries require a native fp8 MXU dot on ``tpu``).
-
-    ``run_dual(x2d, params_g, params_u, ...)``, when set, is the fused
-    gate-up variant: ONE pallas_call contracting the activation tile
-    against two same-shaped weights and emitting ``silu(g) * u`` (the
-    ``silu_mul`` epilogue point) directly.  Entries without it decline
-    dual plans and the gate-up dispatcher falls back to a single
-    concatenated GEMM + jnp epilogue.
-
-    ``activation_skip`` marks entries whose run adapter carries a masked
-    (block-skip) kernel variant for the dynamic activation-sparsity
-    execution class — on a single-placement decision with an
-    ``activation`` axis, the engine hands the adapter the trace-time
-    block maps and dead K-blocks are elided in-kernel.  Entries without
-    it still execute sparse-activation problems correctly (the mask pass
-    is applied to ``x`` regardless); they just never skip.
     """
 
     name: str
-    mode: str                      # dense | compressed | gather
+    mode: str                      # dense | compressed | gather | attention
     fit_blocks: Callable[..., Optional[Blocks]]
     run: Callable[..., jax.Array]
     candidates: Callable[..., Sequence[Blocks]]
-    backends: Tuple[str, ...] = KERNEL_BACKENDS
     priority: int = 0
     quantized: bool = False
-    run_quantized: Optional[Callable[..., jax.Array]] = None
     supported: Optional[Callable[[str], bool]] = None
-    run_dual: Optional[Callable[..., jax.Array]] = None
-    activation_skip: bool = False
 
 
 _REGISTRY: Dict[str, List[KernelEntry]] = {}
@@ -178,8 +161,6 @@ def select(
         return None
     b, ke, o = loc
     for entry in _REGISTRY.get(mode, []):
-        if backend not in entry.backends:
-            continue
         if entry.supported is not None and not entry.supported(backend):
             continue
         blocks = entry.fit_blocks(b, ke, o, n, m, dtype)

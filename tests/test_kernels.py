@@ -11,7 +11,7 @@ from repro.kernels.tile_gemm.kernel import tile_gemm
 from repro.kernels.tile_gemm.ref import tile_gemm_ref
 from repro.kernels.nm_spmm.kernel import nm_spmm
 from repro.kernels.nm_spmm.ref import nm_spmm_ref
-from repro.kernels.nm_spmm_gather.ops import nm_spmm_gather_op
+from repro.kernels.nm_spmm_gather.kernel import nm_spmm_gather
 from repro.kernels.nm_spmm_gather.ref import nm_spmm_gather_ref
 from repro.kernels.flash_attention.ops import flash_attention_op
 from repro.kernels.flash_attention.ref import attention_ref
@@ -72,7 +72,7 @@ def test_nm_spmm_block_shapes():
     pm = nm.pack_meta(c.meta)
     ref = nm_spmm_ref(x, c.values, pm, n)
     for bb, bo, bke in [(128, 128, 512), (256, 256, 1024), (64, 128, 256), (256, 64, 128)]:
-        got = nm_spmm(x, c.values, pm, n, block_b=bb, block_o=bo, block_ke=bke,
+        got = nm_spmm(x, c.values, pm, n, block_b=bb, block_o=bo, block_k=bke,
                       interpret=True)
         _allclose(got, ref, rtol=1e-5)
 
@@ -93,7 +93,7 @@ def test_nm_spmm_gather_sweep(n, b, ke, o, dtype):
     )
     idx = jnp.sort(idx, axis=1).reshape(kc).astype(jnp.int32)
     x = jax.random.normal(jax.random.PRNGKey(1), (b, ke), jnp.float32).astype(dtype)
-    got = nm_spmm_gather_op(x, vals, idx, n=n, interpret=True)
+    got = nm_spmm_gather(x, vals, idx, n, interpret=True)
     _allclose(got, nm_spmm_gather_ref(x, vals, idx, n), rtol=1e-5)
 
 

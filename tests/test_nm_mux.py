@@ -19,10 +19,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.core import nm
 from repro.kernels import dispatch
 from repro.kernels.actsparse import block_maps
-from repro.kernels.nm_spmm.kernel import (
-    mux_form, nm_spmm, nm_spmm_dual, nm_spmm_fp8, nm_spmm_int8,
-    nm_spmm_masked, slab_order,
-)
+from repro.kernels.nm_spmm.kernel import mux_form, nm_spmm, slab_order
 
 FP8 = jnp.float8_e4m3fn
 DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8,
@@ -81,11 +78,11 @@ def test_mux_matches_decompress_then_matmul(n, form, dt, b, bke):
     xs = jax.random.uniform(ks[2], (b, 1), jnp.float32, 0.5, 1.5)
     ws = jax.random.uniform(ks[3], (1, O), jnp.float32, 0.5, 1.5)
     acc = jnp.int32 if dt == "int8" else jnp.float32
-    blocks = dict(block_b=b, block_o=O, block_ke=bke, interpret=True)
-    scaled = {"int8": nm_spmm_int8, "fp8": nm_spmm_fp8}.get(dt)
+    blocks = dict(block_b=b, block_o=O, block_k=bke, interpret=True)
+    scales = dict(x_scale=xs, w_scale=ws) if quant else {}
     want = _mm(x, _dense(v, pm, n))
     if form == "raw":
-        got = scaled(x, v, pm, None, None, n, **blocks)
+        got = nm_spmm(x, v, pm, n, raw=True, **blocks)
         assert got.dtype == acc
         if dt == "int8":  # the int32 accumulator is exact
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -93,24 +90,20 @@ def test_mux_matches_decompress_then_matmul(n, form, dt, b, bke):
     elif quant:
         want = want * xs * ws
     if form == "plain":
-        got = (scaled(x, v, pm, xs, ws, n, **blocks) if quant
-               else nm_spmm(x, v, pm, n, **blocks))
+        got = nm_spmm(x, v, pm, n, **scales, **blocks)
     elif form == "masked":
         kmap, kmask = block_maps(x, b, bke)
         assert int(kmask.sum()) == kmask.size - kmask.shape[0]
-        qargs = (xs, ws) if quant else ()
-        got = nm_spmm_masked(x, v, pm, kmap, kmask, n, *qargs,
-                             acc_dtype=acc, **blocks)
+        got = nm_spmm(x, v, pm, n, kmap=kmap, kmask=kmask, **scales,
+                      **blocks)
     elif form == "dual":
         vu, pmu = _weights(ks[4], ke, n, dt)
         up = _mm(x, _dense(vu, pmu, n))
         if quant:
             wsu = ws[:, ::-1]
             up = up * xs * wsu
-            got = nm_spmm_dual(x, v, pm, vu, pmu, n, xs, ws, wsu,
-                               acc_dtype=acc, **blocks)
-        else:
-            got = nm_spmm_dual(x, v, pm, vu, pmu, n, **blocks)
+            scales["up_scale"] = wsu
+        got = nm_spmm(x, v, pm, n, up=(vu, pmu), **scales, **blocks)
         want = jax.nn.silu(want) * up
     got = np.asarray(got, np.float32)
     want = np.asarray(want, np.float32)

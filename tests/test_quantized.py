@@ -148,13 +148,13 @@ def test_int8_kernel_invoked_not_planned(monkeypatch):
     import repro.kernels.nm_spmm.kernel as nm_kernel
 
     calls = []
-    real = nm_kernel.nm_spmm_int8
+    real = nm_kernel.nm_spmm
 
     def spy(*args, **kwargs):
         calls.append(kwargs.get("interpret"))
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(nm_kernel, "nm_spmm_int8", spy)
+    monkeypatch.setattr(nm_kernel, "nm_spmm", spy)
     cfg = SparsityConfig(n=2, m=4, mode="compressed")
     p_q = q.quantize_linear(_family_params("compressed", _w(64, 32), 2))
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 64))
@@ -563,13 +563,13 @@ def test_sharded_int8_kernel_actually_runs(env, monkeypatch):
     from repro.models.pjit_utils import use_axis_env
 
     calls = []
-    real = nm_kernel.nm_spmm_int8
+    real = nm_kernel.nm_spmm
 
     def spy(*args, **kwargs):
         calls.append(kwargs.get("interpret"))
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(nm_kernel, "nm_spmm_int8", spy)
+    monkeypatch.setattr(nm_kernel, "nm_spmm", spy)
     cfg = SparsityConfig(n=2, m=4, mode="compressed")
     p_q = q.quantize_linear(_sharded_family_params("compressed", 2))
     x = jax.random.normal(jax.random.PRNGKey(1), (32, 512))
